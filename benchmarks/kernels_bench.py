@@ -117,10 +117,10 @@ def bench_exit_head(shapes, iters):
         us_ref = t_of(ref, h, scale, tab, th, iters=iters)
         us_fused = t_of(dispatch.exit_head_gate, h, scale, tab, th,
                         iters=iters)
-        block_v = dispatch.exit_head_block_v(v, d)
+        block_v = dispatch.exit_head_block_v(v, d, b)
         backend = dispatch.select_backend(
             "exit_head",
-            vmem_bytes=dispatch._head_step_bytes(block_v, d))
+            vmem_bytes=dispatch._head_step_bytes(block_v, d, b))
         rows.append({
             "kernel": "exit_head", "shape": f"{b}x{d}x{v}",
             "us_ref": us_ref, "us_fused": us_fused,
@@ -194,5 +194,7 @@ if __name__ == "__main__":
                          "gate result")
     ap.add_argument("--outdir", default="artifacts/bench")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     _, gate = main(outdir=args.outdir, smoke=args.smoke)
     raise SystemExit(0 if gate["ok"] else 1)

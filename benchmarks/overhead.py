@@ -102,4 +102,6 @@ def main(outdir="artifacts/bench"):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     main()

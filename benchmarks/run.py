@@ -36,4 +36,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -83,6 +83,8 @@ def _parser():
 ARGS = _parser().parse_args([])          # defaults; real argv under __main__
 if __name__ == "__main__":
     ARGS = _parser().parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
 import jax                                                  # noqa: E402
 import jax.numpy as jnp                                     # noqa: E402

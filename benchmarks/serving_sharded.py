@@ -58,6 +58,8 @@ if __name__ == "__main__":
         print(f"serving_sharded: XLA_FLAGS already set "
               f"({os.environ['XLA_FLAGS']!r}); --devices ignored",
               file=sys.stderr)
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
 import time                                                # noqa: E402
 

@@ -18,6 +18,7 @@ substrate), and workers can generate any shard without coordination.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 
 import numpy as np
 
@@ -57,8 +58,11 @@ class DatasetConfig:
 
 
 def _rng_for(cfg: DatasetConfig, index: int, split: str):
+    # crc32, not hash(): str hashes are salted per process, and the data
+    # must be the same draw in every process for one seed
+    base = zlib.crc32(f"{cfg.seed}/{split}".encode()) % (2**31 - 1)
     return np.random.RandomState(
-        (hash((cfg.seed, split)) % (2**31 - 1)) ^ (index * 2654435761 % (2**31 - 1)))
+        base ^ (index * 2654435761 % (2**31 - 1)))
 
 
 def synth_mnist_sample(cfg: DatasetConfig, index: int, split="train"):

@@ -547,8 +547,7 @@ class DartEngine:
         cum, total = [], 0.0
 
         def flops_of(fn, *args):
-            from repro.compat import cost_analysis_dict
-            c = cost_analysis_dict(jax.jit(fn).lower(*args).compile())
+            c = jax.jit(fn).lower(*args).compile().cost_analysis()
             return float(c.get("flops", 0.0))
 
         for s in range(self.n_exits):

@@ -1235,9 +1235,11 @@ class ContinuousLMDecoder:
         eng = self.eng
         if not self.active.any():
             return []
+        # pass a copy: on CPU the device buffer may alias the host array,
+        # and the reset below would then land before the async step reads
         x, self.alpha = self._embed_step()(
             eng.params, jnp.asarray(self.tokens), self.alpha,
-            jnp.asarray(self.fresh))
+            jnp.asarray(self.fresh.copy()))
         self.fresh[:] = 0
         eng.state, (self.pages, toks_out, stg_out) = self._decode_step()(
             eng.params, eng.state, self.pages, x, self.alpha,

@@ -62,7 +62,6 @@ import threading
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 BACKENDS = ("pallas", "pallas-interpret", "xla")
@@ -143,61 +142,108 @@ def resolve_interpret(interpret) -> bool:
 #: entries when tuning on real hardware; unlisted shapes take the
 #: class defaults below.
 _AUTOTUNE: dict[tuple[str, str], dict] = {
-    # small vocabularies: 8 rows per grid step amortize launch overhead
-    ("exit_gate", "v<=2048"): {"block_b": 8},
-    # LM vocabularies: one VMEM-resident row per step
-    ("exit_gate", "v>2048"): {"block_b": 1},
+    # small vocabularies: a whole serving bucket per grid step
+    ("exit_gate", "v<=2048"): {"block_b": 128},
+    # LM vocabularies: one 8-row sublane tile per step
+    ("exit_gate", "v>2048"): {"block_b": 8},
     # one image per grid step (the image IS the block)
     ("difficulty", "default"): {},
     # fused exit head: vocab block target (shrunk to the VMEM budget
-    # and to a divisor of V by exit_head_block_v)
+    # and to a lane-aligned divisor of V by exit_head_block_v)
     ("exit_head", "default"): {"block_v": 2048},
     # paged KV gather: one page per grid step (the page IS the block)
     ("paged_gather", "default"): {},
 }
 
+#: TPU tiling: a block's last dim is a multiple of 128 lanes (or the
+#: whole array dim) and its second-to-last a multiple of 8 sublanes.
+_LANES = 128
+_SUBLANES = 8
+
 
 def gate_block_b(b: int, v: int) -> int:
-    """Rows per grid step for the exit gate: the autotune entry for this
-    vocab class, shrunk to a divisor of the (power-of-two bucketed)
-    batch."""
+    """Rows per grid step for the exit gate.  The whole batch when it
+    is at most the autotune target or not a multiple of 8 (a block
+    equal to the array is always legal); otherwise the largest multiple
+    of 8 that is <= the target and divides the batch."""
     key = ("exit_gate", "v<=2048" if v <= 2048 else "v>2048")
-    block = _AUTOTUNE[key]["block_b"]
-    block = max(1, min(block, b))
+    target = _AUTOTUNE[key]["block_b"]
+    if b <= target or b % _SUBLANES:
+        return b
+    block = target - target % _SUBLANES
     while b % block:
-        block -= 1
+        block -= _SUBLANES
     return block
 
 
-def exit_head_block_v(v: int, d: int,
+def exit_head_block_v(v: int, d: int, b: int = 8, itemsize: int = 4,
                       budget: int = VMEM_BUDGET_BYTES) -> int:
-    """Vocab block for the fused exit head: the largest divisor of ``v``
-    that is <= the autotune target AND fits the VMEM budget."""
+    """Vocab block for the fused exit head: the largest lane-aligned
+    divisor of ``v`` that is <= the autotune target AND fits the VMEM
+    budget for ``b`` rows and an ``itemsize``-byte table.  A vocabulary
+    with no lane-aligned divisor takes one whole-vocab block.  When no
+    candidate fits, the smallest is returned and the budget check in
+    ``exit_head_gate`` routes the call to the XLA reference."""
     target = _AUTOTUNE[("exit_head", "default")]["block_v"]
-    cap = max(1, min(v, target, budget // max(_head_step_bytes(1, d), 1)))
-    while cap > 1 and (v % cap or _head_step_bytes(cap, d) > budget):
-        cap -= 1
-    return cap
+    aligned = [k for k in range(_LANES, min(v, target) + 1, _LANES)
+               if v % k == 0]
+    if not aligned:
+        return v
+    fits = [k for k in aligned
+            if _head_step_bytes(k, d, b, itemsize) <= budget]
+    return max(fits) if fits else aligned[0]
 
 
 # ---------------------------------------------------------------------------
-# per-grid-step VMEM footprints (fp32 working set, temporaries included)
+# per-grid-step VMEM footprints: what the TPU compiler allocates, tile
+# padding, double-buffered operands and fp32 temporaries included (fitted
+# to v5e compiles; tests/test_tpu_compile.py holds them to the compiler)
 # ---------------------------------------------------------------------------
 
-def _gate_step_bytes(block_b: int, v: int) -> int:
-    return block_b * v * 4 * 2            # logits block + exp temporary
+#: fixed per-kernel allowance (semaphores, small scratch, masks)
+_VMEM_SLACK = 256 * 1024
 
 
-def _difficulty_step_bytes(h: int, w: int, c: int) -> int:
-    return h * w * (c + 3) * 4            # image + gray + 2 stencil temps
+def _vmem_bytes(shape, itemsize: int) -> int:
+    """Bytes one VMEM buffer of ``shape`` occupies once tiled: the last
+    dim padded to 128 lanes, the second-to-last to the dtype's sublane
+    tile (8 rows of 32-bit words, so 16 for bf16)."""
+    shape = (1,) * max(0, 2 - len(shape)) + tuple(shape)
+    sub = _SUBLANES * max(1, 4 // itemsize)
+    lead = 1
+    for n in shape[:-2]:
+        lead *= n
+    return (lead * -(-shape[-2] // sub) * sub
+            * -(-shape[-1] // _LANES) * _LANES * itemsize)
 
 
-def _head_step_bytes(block_v: int, d: int) -> int:
-    return (block_v * d + 3 * d + 2 * block_v) * 4   # table block + row
+def _gate_step_bytes(block_b: int, v: int, itemsize: int = 4) -> int:
+    # double-buffered logits block + its fp32 working copy
+    return (2 * _vmem_bytes((block_b, v), itemsize)
+            + _vmem_bytes((block_b, v), 4) + _VMEM_SLACK)
 
 
-def _paged_step_bytes(psz: int, trailing: int) -> int:
-    return psz * trailing * 4 * 2         # one page in + one block out
+def _difficulty_step_bytes(h: int, w: int, c: int,
+                           itemsize: int = 4) -> int:
+    # double-buffered (C, H, W) image block + fp32 planes: the image,
+    # gray, and the stencil/Sobel temporaries
+    return (2 * _vmem_bytes((c, h, w), itemsize)
+            + (c + 6) * _vmem_bytes((h, w), 4) + _VMEM_SLACK)
+
+
+def _head_step_bytes(block_v: int, d: int, b: int = 8,
+                     itemsize: int = 4) -> int:
+    # double-buffered unembed block and hidden rows, fp32 normalized
+    # rows, and fp32 (B, block_v) logits / exp / iota temporaries
+    return (2 * _vmem_bytes((block_v, d), itemsize)
+            + 2 * _vmem_bytes((b, d), itemsize)
+            + 2 * _vmem_bytes((b, d), 4)
+            + 3 * _vmem_bytes((b, block_v), 4) + _VMEM_SLACK)
+
+
+def _paged_step_bytes(page_shape, itemsize: int = 4) -> int:
+    # one page in + one page out, each double-buffered
+    return 4 * _vmem_bytes(page_shape, itemsize) + _VMEM_SLACK
 
 
 # ---------------------------------------------------------------------------
@@ -207,8 +253,8 @@ def _paged_step_bytes(psz: int, trailing: int) -> int:
 def _maybe_shard_map(fn, mesh, axis, in_specs, out_specs):
     if mesh is None:
         return fn
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_vma=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _axis_size(mesh, axis) -> int:
@@ -284,8 +330,9 @@ def exit_gate(logits, thresholds, *, mesh=None, axis: str = "data",
     then shard_map-wrapped so each replica gates its local rows."""
     b, v = logits.shape
     block_b = gate_block_b(max(b // _axis_size(mesh, axis), 1), v)
-    chosen = _resolve("exit_gate", _gate_step_bytes(block_b, v), backend,
-                      mesh, axis, b)
+    chosen = _resolve("exit_gate",
+                      _gate_step_bytes(block_b, v, logits.dtype.itemsize),
+                      backend, mesh, axis, b)
     return _exit_gate_impl(logits, thresholds, backend=chosen,
                            block_b=block_b, mesh=mesh, axis=axis)
 
@@ -301,7 +348,9 @@ def softmax_confidence(logits, *, mesh=None, axis: str = "data",
         flat_b *= s
     block_b = gate_block_b(max(flat_b // _axis_size(mesh, axis), 1),
                            shape[-1])
-    chosen = _resolve("exit_gate", _gate_step_bytes(block_b, shape[-1]),
+    chosen = _resolve("exit_gate",
+                      _gate_step_bytes(block_b, shape[-1],
+                                       logits.dtype.itemsize),
                       backend, mesh, axis, shape[0])
     if mesh is not None and logits.ndim != 2:
         chosen = "xla"          # only 2-D rows shard_map cleanly here
@@ -347,7 +396,9 @@ def difficulty_components(images, cfg=None, *, mesh=None,
     from repro.core.difficulty import DEFAULT
     cfg = DEFAULT if cfg is None else cfg
     b, h, w, c = images.shape
-    chosen = _resolve("difficulty", _difficulty_step_bytes(h, w, c),
+    chosen = _resolve("difficulty",
+                      _difficulty_step_bytes(h, w, c,
+                                             images.dtype.itemsize),
                       backend, mesh, axis, b)
     return _difficulty_impl(images, cfg=cfg, backend=chosen, mesh=mesh,
                             axis=axis)
@@ -422,11 +473,9 @@ def paged_gather(pages, page_table, *, mesh=None, axis: str = "data",
     a sharded step pass ``mesh=``/``axis=``: pages shard over the page
     axis, slots over the table axis (the decoder's range-partitioned
     allocator keeps each slot's pages on its own replica)."""
-    psz = pages.shape[1]
-    trailing = 1
-    for d in pages.shape[2:]:
-        trailing *= d
-    chosen = _resolve("paged_gather", _paged_step_bytes(psz, trailing),
+    chosen = _resolve("paged_gather",
+                      _paged_step_bytes(pages.shape[1:],
+                                        pages.dtype.itemsize),
                       backend, mesh, axis, page_table.shape[0])
     if chosen != "xla" and mesh is not None \
             and pages.shape[0] % _axis_size(mesh, axis):
@@ -446,9 +495,11 @@ def exit_head_gate(h, scale, table, thresholds, *, eps: float = 1e-6,
     the (B, V) logits in HBM (online softmax over vocab blocks)."""
     b, d = h.shape
     v = table.shape[0]
-    block_v = exit_head_block_v(v, d)
-    chosen = _resolve("exit_head", _head_step_bytes(block_v, d), backend,
-                      mesh, axis, b)
+    rows = max(b // _axis_size(mesh, axis), 1)
+    isz = table.dtype.itemsize
+    block_v = exit_head_block_v(v, d, rows, isz)
+    chosen = _resolve("exit_head", _head_step_bytes(block_v, d, rows, isz),
+                      backend, mesh, axis, b)
     return _exit_head_impl(h, scale, table, thresholds, eps=eps,
                            backend=chosen, block_v=block_v, mesh=mesh,
                            axis=axis)

@@ -17,11 +17,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:                                      # pltpu is absent on some builds
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:                       # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(tab_ref, pages_ref, out_ref):
@@ -31,8 +27,6 @@ def _kernel(tab_ref, pages_ref, out_ref):
 def paged_gather_pallas(pages, page_table, *, interpret=None):
     """pages (N, psz, ...), page_table (S, P) int32 -> (S, P*psz, ...)."""
     from repro.kernels.dispatch import resolve_interpret
-    if pltpu is None:                     # pragma: no cover
-        raise NotImplementedError("pallas TPU grid specs unavailable")
     s, p = page_table.shape
     psz = pages.shape[1]
     rest = pages.shape[2:]

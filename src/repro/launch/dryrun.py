@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell this produces (artifacts/dryrun/<arch>__<shape>__<mesh>.json):
@@ -17,6 +14,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import re
 import time
 import traceback
@@ -25,7 +23,6 @@ import jax
 
 from repro.configs import registry
 from repro.launch.mesh import make_production_mesh
-from repro.compat import cost_analysis_dict
 from repro.launch import steps as steps_mod
 
 COLLECTIVE_KINDS = ("all-gather", "all-reduce", "reduce-scatter",
@@ -110,7 +107,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     compiled = lowered.compile()
     t2 = time.time()
 
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     ma = compiled.memory_analysis()
     hlo = compiled.as_text()
     bf16_model = bundle.meta.get("bf16", True) and not reduced
@@ -126,7 +123,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
         plow = jax.jit(pb.step, in_shardings=pb.in_shardings).lower(
             *pb.inputs)
         pcomp = plow.compile()
-        pca = cost_analysis_dict(pcomp)
+        pca = pcomp.cost_analysis()
         pcoll = parse_collectives(pcomp.as_text(), bf16_model=bf16_model)
         extra = (bundle.meta["scan_layers_total"]
                  - bundle.meta["scan_body_instances"])
@@ -230,4 +227,7 @@ def main():
 
 
 if __name__ == "__main__":
+    # the production meshes need 512 host devices: set before the first
+    # backend use, by this command only (importers keep their own flags)
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     main()

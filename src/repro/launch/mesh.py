@@ -14,17 +14,10 @@ from __future__ import annotations
 
 import jax
 
-# jax >= 0.5 takes axis_types=(AxisType.Auto, ...); 0.4.x has neither the
-# enum nor the kwarg (same version-gate pattern as the `shard_map` import
-# in models/moe.py).
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
 
 def _make_mesh(shape, axes):
-    if _AXIS_TYPE is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(_AXIS_TYPE.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -8,6 +8,7 @@ Param leaves replaced by arrays — see ``sharding.unzip``).
 from __future__ import annotations
 
 import math
+import zlib
 
 import jax
 import jax.numpy as jnp
@@ -19,10 +20,12 @@ Array = jax.Array
 
 
 def rng(key: Array, name: str) -> Array:
-    """Deterministic named RNG stream."""
+    """Deterministic named RNG stream.  crc32, not hash(): str hashes
+    are salted per process, and one seed must give the same weights in
+    every process."""
     folded = key
     for token in name.split("/"):
-        folded = jax.random.fold_in(folded, hash(token) % (2**31 - 1))
+        folded = jax.random.fold_in(folded, zlib.crc32(token.encode()))
     return folded
 
 

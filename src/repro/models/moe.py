@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 
 from repro.parallel.sharding import Param
 from repro.models import layers as L
@@ -283,7 +282,7 @@ def moe_apply(p, x, cfg: MoEConfig, *, mesh=None, dp_axes=("data",),
             xspec = P(dp + (model_axis,), None)
             body = partial(_moe_body_a2a, cfg=cfg, axis_name=model_axis,
                            ep=ep, all_axes=tuple(mesh.axis_names))
-            out, aux = shard_map(
+            out, aux = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(xspec, P(None, None), wspec_g, wspec_g, wspec_d),
                 out_specs=(xspec, P()),
@@ -296,7 +295,7 @@ def moe_apply(p, x, cfg: MoEConfig, *, mesh=None, dp_axes=("data",),
         body = partial(_moe_body, cfg=cfg, ep_mode=ep_mode,
                        axis_name=model_axis,
                        all_axes=tuple(mesh.axis_names))
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             body, mesh=mesh,
             in_specs=(xspec, P(None, None), wspec_g, wspec_g, wspec_d),
             out_specs=(xspec, P()),

@@ -201,9 +201,7 @@ def resolve_spec(shape: Sequence[int], axes: LogicalAxes, rules: Rules,
 def tree_specs(axes_tree, shapes_tree, rules: Rules, mesh: Mesh,
                collect_downgrades: list[Downgrade] | None = None):
     """Build a PartitionSpec tree matching the param tree."""
-    # jax.tree.flatten_with_path landed after 0.4.37; the tree_util
-    # spelling works on every version we support.
-    paths_axes = jax.tree_util.tree_flatten_with_path(
+    paths_axes = jax.tree.flatten_with_path(
         axes_tree, is_leaf=lambda x: isinstance(x, tuple) and all(
             isinstance(e, (str, type(None))) for e in x))
     flat_axes, treedef = paths_axes

@@ -48,6 +48,7 @@ def test_parse_collectives_classes_and_wire_factor():
 
 CELL_SCRIPT = textwrap.dedent("""
     import os, sys, json
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     sys.path.insert(0, %r)
     from repro.launch.dryrun import run_cell
     arch, shape, outdir = sys.argv[1], sys.argv[2], sys.argv[3]

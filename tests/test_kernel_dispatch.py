@@ -98,7 +98,15 @@ def test_vmem_boundary_end_to_end(monkeypatch):
 
     monkeypatch.setattr(KMOD, "exit_gate_pallas", spy)
     budget = dispatch.VMEM_BUDGET_BYTES
-    v_under = budget // 8           # block_b=1 -> v * 8 bytes == budget
+    # the widest f32 row whose block_b=1 footprint still fits the budget
+    lo, hi = 1, budget
+    while lo < hi:
+        mid = (lo + hi + 1) // 2
+        if dispatch._gate_step_bytes(1, mid, 4) <= budget:
+            lo = mid
+        else:
+            hi = mid - 1
+    v_under = lo
     v_over = v_under + 1
     rng = np.random.RandomState(0)
     with dispatch.force_backend("pallas-interpret"):

@@ -82,7 +82,9 @@ def test_microbatching_matches_plain_step():
 def test_microbatching_trains():
     mc = AlexNetConfig(img_res=32, n_classes=10,
                        channels=(8, 16, 24, 16, 16), fc_dims=(64, 32))
-    tr = Trainer(mc, TrainConfig(batch_size=16, steps=25, lr=3e-3,
+    # a short warmup: over the default 20 of 25 steps the learning rate
+    # stays too small to move the loss off its initial value
+    tr = Trainer(mc, TrainConfig(batch_size=16, steps=25, lr=3e-3, warmup=5,
                                  microbatches=4, log_every=5), DATA)
     h = tr.run()
     assert min(losses(h)[1:]) < losses(h)[0]
