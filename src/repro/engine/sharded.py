@@ -45,7 +45,6 @@ which is bit-identical to the eager oracle (see docs/kernels.md).
 from __future__ import annotations
 
 import dataclasses
-import time
 import warnings
 
 import jax
@@ -58,6 +57,7 @@ from repro.core import thresholds as TH
 from repro.engine import state as ST
 from repro.engine.engine import DartEngine
 from repro.engine.state import EngineState
+from repro.obs import NULL_SPAN, OBS, span
 
 def _silence_donation_warning():
     """CPU backends ignore donation and warn per step; donation still
@@ -197,8 +197,11 @@ class ShardedDartEngine(DartEngine):
         def step(params, state, x, valid, *aux):
             self._count_trace(key)
             logits = self._forward_traced(params, x)     # (E, bp, C)
-            alpha = aux[0] if with_alpha \
-                else self._diff_fn(x, self.dcfg, **self.kernel_kw)
+            if with_alpha:
+                alpha = aux[0]
+            else:
+                with jax.named_scope("difficulty"):
+                    alpha = self._diff_fn(x, self.dcfg, **self.kernel_kw)
             eff = TH.adapt_thresholds(state.tau, self._coef_traced(state),
                                       alpha, state.beta_diff)
             exit_idx, conf, pred = self._route_traced(logits, eff,
@@ -252,7 +255,9 @@ class ShardedDartEngine(DartEngine):
                 continue
             th_i = eff[:, i] if i < e - 1 \
                 else jnp.full((bp,), -1.0, jnp.float32)
-            c, _, p, f = KD.exit_gate(logits[i], th_i, **self.kernel_kw)
+            with jax.named_scope(f"gate{i}"):
+                c, _, p, f = KD.exit_gate(logits[i], th_i,
+                                          **self.kernel_kw)
             confs.append(c)
             preds.append(p)
             # Alg. 1 line 12: the final exit accepts unconditionally,
@@ -367,7 +372,6 @@ class ShardedDartEngine(DartEngine):
                 for a, z in self.compactor.chunks(b)]
             out = {k: np.concatenate([p[k] for p in parts])
                    for k in ("pred", "conf", "exit_idx", "alpha", "macs")}
-            out["latency_s"] = sum(p["latency_s"] for p in parts)
         else:
             out = self._infer_chunk(x, mode, record, alpha=alpha,
                                     min_exit=min_exit)
@@ -384,32 +388,38 @@ class ShardedDartEngine(DartEngine):
 
     def _infer_chunk(self, x, mode, record, alpha=None,
                      min_exit: int = 0) -> dict:
-        t0 = time.time()
         b = x.shape[0]
         bp = self.bucket_key(b)
         if mode == "masked":
-            xp, valid = self._pad_batch(x, bp)
-            step = self._masked_step(bp, record, alpha is not None,
-                                     min_exit=min_exit)
-            if alpha is None:
-                self.state, out = step(self.params, self.state, xp, valid)
-            else:
-                ap = jax.device_put(jnp.asarray(self.compactor.pad(
-                    np.asarray(alpha, np.float32), bp)), self._row)
-                self.state, out = step(self.params, self.state, xp, valid,
-                                       ap)
-            # Outputs stay ON DEVICE (lazy): a serving loop that doesn't
-            # read them immediately pipelines compiled steps back to
-            # back through the donated state chain.  np.asarray() on any
-            # value materializes it.
-            res = {k: v[:b] for k, v in out.items()}
+            # obs phases: ``put`` (host pad/cast + copies to the device),
+            # ``launch`` (the async step dispatch + output slicing)
+            with span("put") if OBS.enabled else NULL_SPAN as sp:
+                xp, valid = self._pad_batch(x, bp)
+                ap = None if alpha is None else jax.device_put(
+                    jnp.asarray(self.compactor.pad(
+                        np.asarray(alpha, np.float32), bp)), self._row)
+                if OBS.enabled:
+                    sp.set(bytes=int(xp.nbytes + valid.nbytes) + (
+                        0 if ap is None else int(ap.nbytes)))
+            with span("launch") if OBS.enabled else NULL_SPAN:
+                step = self._masked_step(bp, record, alpha is not None,
+                                         min_exit=min_exit)
+                if ap is None:
+                    self.state, out = step(self.params, self.state, xp,
+                                           valid)
+                else:
+                    self.state, out = step(self.params, self.state, xp,
+                                           valid, ap)
+                # Outputs stay ON DEVICE (lazy): a serving loop that
+                # doesn't read them immediately pipelines compiled steps
+                # back to back through the donated state chain.
+                # np.asarray() on any value materializes it.
+                res = {k: v[:b] for k, v in out.items()}
         else:
             res = self._compacted_chunk(x, bp, record, alpha=alpha,
                                         min_exit=min_exit)
         if record:
             self._pending += b
-        res["latency_s"] = time.time() - t0
-        self.total_latency_s += res["latency_s"]
         return res
 
     def _compacted_chunk(self, x, bp, record, alpha=None,
@@ -553,7 +563,6 @@ class ShardedDartEngine(DartEngine):
         out = OBS_STATS.engine_summary(
             ST.telemetry_totals(self.state, sharded=True))
         out.update(
-            total_latency_s=self.total_latency_s,
             active_strategy=AD.STRATEGIES[
                 int(self.state.adaptive["active_strategy"])],
             replicas=self.n_replicas,

@@ -21,6 +21,7 @@ import numpy as np
 
 from repro.core import adaptive as AD
 from repro.core.routing import DartParams
+from repro.obs import to_host
 
 _FIELDS = ("tau", "coef", "beta_diff", "beta_opt", "adaptive",
            "served", "exit_counts", "total_macs", "since_update",
@@ -183,14 +184,17 @@ def record_requests(state: EngineState, latencies_ms,
     k, w = lat.shape[0], state.lat_ms.shape[0]
     if k == 0:
         return state
-    buf = np.asarray(state.lat_ms).copy()
-    idx = (int(state.lat_ptr) + np.arange(k)) % w
+    # three blocking reads of the newest state (obs ``sync`` spans)
+    buf = to_host(state.lat_ms, "latency_ring").copy()
+    idx = (int(to_host(state.lat_ptr, "latency_ring")) + np.arange(k)) % w
     buf[idx] = lat
     n_miss = int(np.sum(missed)) if missed is not None else 0
     return dataclasses.replace(
         state,
         lat_ms=jnp.asarray(buf),
-        lat_ptr=jnp.asarray((int(state.lat_ptr) + k) % w, jnp.int32),
+        lat_ptr=jnp.asarray(
+            (int(to_host(state.lat_ptr, "latency_ring")) + k) % w,
+            jnp.int32),
         lat_count=state.lat_count + jnp.asarray(k, jnp.int32),
         deadline_miss=state.deadline_miss + jnp.asarray(n_miss, jnp.int32))
 

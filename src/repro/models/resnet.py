@@ -173,12 +173,16 @@ def num_stages(cfg: ResNetConfig) -> int:
 def resnet_forward(params, images, cfg: ResNetConfig, *, mesh=None,
                    train=False):
     updates: dict = {}
-    x = apply_stem(params, images, cfg, train=train, updates=updates)
+    with jax.named_scope("stem"):
+        x = apply_stem(params, images, cfg, train=train, updates=updates)
     logits = []
     for s in range(num_stages(cfg)):
-        x = apply_stage(params, x, s, cfg, train=train, updates=updates)
+        with jax.named_scope(f"stage{s}"):
+            x = apply_stage(params, x, s, cfg, train=train,
+                            updates=updates)
         if s in cfg.exit_stages or s == num_stages(cfg) - 1:
-            logits.append(apply_exit(params, x, s, cfg))
+            with jax.named_scope(f"exit{s}"):
+                logits.append(apply_exit(params, x, s, cfg))
     return {"exit_logits": jnp.stack(logits), "bn_updates": updates}
 
 

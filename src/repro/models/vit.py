@@ -144,11 +144,14 @@ def vit_forward(params, images, cfg: ViTConfig, *, mesh=None, train=False):
     """All-exits forward (training / masked serving).
 
     Returns {"exit_logits": (n_exits, B, n_classes)}."""
-    x = apply_stem(params, images, cfg)
+    with jax.named_scope("stem"):
+        x = apply_stem(params, images, cfg)
     logits = []
     for s in range(num_stages(cfg)):
-        x = apply_stage(params, x, s, cfg)
-        logits.append(apply_exit(params, x, s, cfg))
+        with jax.named_scope(f"stage{s}"):
+            x = apply_stage(params, x, s, cfg)
+        with jax.named_scope(f"exit{s}"):
+            logits.append(apply_exit(params, x, s, cfg))
     return {"exit_logits": jnp.stack(logits)}
 
 

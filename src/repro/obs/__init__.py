@@ -2,10 +2,12 @@
 
 One switch, three surfaces:
 
-* **per-request tracing** (:mod:`repro.obs.trace`) — host-side spans
-  ``admit -> queue_wait -> bucket/slot -> compiled_step -> exit |
-  escalate | shed`` in a bounded drop-oldest ring; JSONL + Chrome
-  ``trace_event`` export (``tools/trace_view.py``).
+* **tracing** (:mod:`repro.obs.trace`) — host-side spans in a bounded
+  drop-oldest ring: per request (``admit -> queue_wait -> bucket/slot
+  -> compiled_step -> exit | escalate | shed``), and per thread
+  (:func:`span`: the dispatcher's phases, the ``sync`` of every
+  blocking device→host read, :func:`to_host`); JSONL + Chrome
+  ``trace_event`` export on the wall clock (``tools/trace_view.py``).
 * **metrics registry** (:mod:`repro.obs.metrics`) — counters / gauges /
   histograms with label sets and a Prometheus text exposition (file
   and stdlib-``http.server`` endpoint); :mod:`repro.obs.adapters`
@@ -26,27 +28,30 @@ Usage::
     print(obs.OBS.registry.render())        # Prometheus text
 
 Disabled (the default) is zero-cost on the hot path: every
-instrumentation site is a single ``if OBS.enabled`` attribute check,
-spans are recorded only from host-side scheduler code (never inside
-jitted step functions), and no extra host syncs are introduced —
-the differential suites pin bit-identical outputs and unchanged
-``trace_counts`` with obs off.  Enabled-mode overhead is gated in CI
-(``obs.overhead`` in ``benchmarks/baselines/smoke.json``, <=5%).
+instrumentation site is a single ``if OBS.enabled`` attribute check
+(span sites enter the inert :data:`NULL_SPAN`: no clock read, no
+allocation), spans are recorded only from host-side scheduler code
+(never inside jitted step functions), and no extra host syncs are
+introduced — the differential suites pin bit-identical outputs and
+unchanged ``trace_counts`` with obs off.  What enabled costs is
+measured on the chip (docs/observability.md).
 """
 from __future__ import annotations
 
 import threading
 
+import numpy as np
+
 from repro.obs import log  # noqa: F401  (re-export)
 from repro.obs.metrics import (Registry, parse_prometheus,
                                render_prometheus, start_http_server,
                                write_textfile)
-from repro.obs.trace import Tracer, chrome_trace
+from repro.obs.trace import NULL_SPAN, Tracer, chrome_trace, wall_offset_ns
 
 __all__ = ["OBS", "configure", "reset", "is_enabled", "get_registry",
-           "get_tracer", "flush_textfile", "Registry", "Tracer",
-           "chrome_trace", "render_prometheus", "parse_prometheus",
-           "log"]
+           "get_tracer", "flush_textfile", "span", "to_host", "NULL_SPAN",
+           "Registry", "Tracer", "chrome_trace", "render_prometheus",
+           "parse_prometheus", "log"]
 
 DEFAULT_TRACE_CAPACITY = 16384
 
@@ -82,6 +87,28 @@ def get_registry() -> Registry:
 
 def get_tracer() -> Tracer:
     return OBS.tracer
+
+
+def span(name: str, **attrs):
+    """``with obs.span("fetch"):`` — a span of the calling thread on the
+    global tracer (see :meth:`repro.obs.trace.Tracer.span`).  Hot-path
+    sites guard it: ``with obs.span(...) if OBS.enabled else NULL_SPAN``."""
+    return OBS.tracer.span(name, **attrs)
+
+
+def to_host(value, site: str) -> np.ndarray:
+    """``np.asarray(value)``: a blocking device→host read.  With obs on
+    it is a ``sync`` span (``site``, ``bytes``), child of the span it
+    blocks, and counts ``dart_device_syncs_total{site}``."""
+    if not OBS.enabled:
+        return np.asarray(value)
+    with OBS.tracer.span("sync", site=site) as sp:
+        out = np.asarray(value)
+        sp.set(bytes=int(out.nbytes))
+    OBS.registry.counter("dart_device_syncs_total",
+                         "blocking device-to-host reads by call site",
+                         ("site",)).inc(1, site=site)
+    return out
 
 
 def configure(enabled: bool | None = None, *,
@@ -124,6 +151,7 @@ def configure(enabled: bool | None = None, *,
     if http_port is not None and OBS._http is None:
         OBS._http = start_http_server(OBS.registry, port=http_port)
     if OBS.enabled:
+        OBS.tracer.wall_offset_ns = wall_offset_ns()
         # kernel dispatch decisions are always counted (trace-time
         # bookkeeping, like trace_counts); export them once enabled
         from repro.obs import adapters

@@ -50,15 +50,17 @@ def _latency_hist(reg):
 # push side (hot path; callers guard with OBS.enabled)
 # ---------------------------------------------------------------------------
 
-def record_admit(sched, req, action: str, t0: float, t1: float) -> None:
-    """One admitted (or dropped-at-admission) request: the ``admit``
-    span covers the admission work itself (the Eq. 8 estimate)."""
+def record_admit(sched, req, action: str, span) -> None:
+    """One admitted (or dropped-at-admission) request, called inside its
+    ``admit`` span: the span covers the admission work itself (the
+    Eq. 8 estimate, with its ``put`` and ``sync`` children) and the
+    queue push."""
     lane = _lane(req.lane)
     alpha = float(np.mean(req.alpha)) if req.n else 0.0
-    OBS.tracer.record("admit", ts=t0, dur=t1 - t0, rid=req.rid,
-                      lane=req.lane, n=req.n, alpha=alpha,
-                      predicted_cost=float(req.predicted_cost),
-                      priority=req.priority, action=action)
+    span.set(rid=req.rid, lane=req.lane, n=req.n, alpha=alpha,
+             predicted_cost=float(req.predicted_cost),
+             priority=req.priority, action=action)
+    t1 = sched._clock()
     reg = OBS.registry
     reg.counter("dart_requests_total", "requests submitted by lane",
                 ("lane",)).inc(1, lane=lane)
@@ -70,11 +72,13 @@ def record_admit(sched, req, action: str, t0: float, t1: float) -> None:
                     ("lane", "action")).inc(1, lane=lane, action=action)
 
 
-def record_bucket(sched, reqs: list, reason: str, now: float) -> None:
-    """One flushed bucket: which lane, how many requests/samples, and
-    WHY it flushed (deadline pressure / size / hold / forced)."""
-    OBS.tracer.record("bucket", ts=now, lane=reqs[0].lane,
-                      n_requests=len(reqs),
+def record_bucket(sched, reqs: list, reason: str, now: float,
+                  bid: int) -> None:
+    """One flushed bucket: its id, which lane, which requests and how
+    many samples, and WHY it flushed (deadline pressure / size / hold /
+    forced)."""
+    OBS.tracer.record("bucket", ts=now, lane=reqs[0].lane, bid=bid,
+                      rids=[r.rid for r in reqs], n_requests=len(reqs),
                       n_samples=sum(r.n for r in reqs), reason=reason)
     OBS.registry.counter("dart_flushes_total", "bucket flushes by reason",
                          ("reason",)).inc(1, reason=reason)
@@ -84,10 +88,12 @@ def record_completed(server, reqs: list, results: list, t_dispatch: float,
                      now: float) -> None:
     """Completed requests of one materialized bucket: spans
     ``queue_wait`` (submit -> dispatch) and ``compiled_step``
-    (dispatch -> materialized), plus the ``exit`` span joining the
-    host-side view (predicted cost, deadline slack) with the realized
-    exit depths the engine computed."""
+    (dispatch -> materialized), both with the bucket's ``bid``, plus
+    the ``exit`` span joining the host-side view (predicted cost,
+    deadline slack) with the realized exit depths the engine
+    computed."""
     reg, tr = OBS.registry, OBS.tracer
+    bid = tr.bid
     hist = _latency_hist(reg)
     comp = reg.counter("dart_requests_completed_total",
                        "requests completed by lane", ("lane",))
@@ -104,10 +110,10 @@ def record_completed(server, reqs: list, results: list, t_dispatch: float,
         slack = None if r.deadline_s is None else r.deadline_s - now
         tr.record("queue_wait", ts=r.t_submit,
                   dur=max(t_dispatch - r.t_submit, 0.0),
-                  rid=r.rid, lane=r.lane)
+                  rid=r.rid, lane=r.lane, bid=bid)
         tr.record("compiled_step", ts=t_dispatch,
                   dur=max(now - t_dispatch, 0.0), rid=r.rid, lane=r.lane,
-                  n=r.n)
+                  n=r.n, bid=bid)
         tr.record("exit", ts=now, rid=r.rid, lane=r.lane,
                   exits=exit_idx.tolist(), members=members.tolist(),
                   predicted_cost=float(r.predicted_cost),

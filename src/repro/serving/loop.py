@@ -32,6 +32,16 @@ Pipelining: with a sharded engine, dispatched outputs stay ON DEVICE
 ``pipeline_depth`` buckets in flight and only materializes (resolving
 futures, folding latency telemetry into ``EngineState``) when the
 pipeline is full or there is nothing left to dispatch.
+
+Tracing (obs on): the dispatcher thread's time is tiled by exclusive
+phase spans, each carrying the bucket id ``bid`` it works for —
+``wait`` (``why``: empty / timer / inflight-poll), ``select`` (flush
+decision + take, ``reason``), ``gather``, ``put`` and ``launch`` (the
+engine's), ``fetch`` (output reads + per-request split), ``fold``
+(telemetry) and ``resolve`` (futures).  The time between two phases
+counts to the one that ended, as its ``tail`` (``Tracer.tile``).
+Blocking device→host reads are ``sync`` children
+(docs/observability.md).
 """
 from __future__ import annotations
 
@@ -46,7 +56,7 @@ import numpy as np
 
 from repro.core import daes as DAES
 from repro.core import difficulty as DIFF
-from repro.obs import OBS
+from repro.obs import NULL_SPAN, OBS, span, to_host
 from repro.obs import adapters as OBS_A
 from repro.obs import log as OBS_LOG
 from repro.serving.planner import AdmissionPlanner
@@ -127,6 +137,7 @@ class _BucketScheduler:
         self._stop = False
         self._closed = False
         self._service_s = 0.0        # EMA of bucket service time
+        self._bid = 0                # next bucket id (advanced with obs on)
         self.last_error: Exception | None = None
         self.counters = {"submitted": 0, "completed": 0, "degraded": 0,
                          "flush_deadline": 0, "flush_size": 0,
@@ -189,20 +200,21 @@ class _BucketScheduler:
         """Enqueue one request; resolves to its per-request result dict
         (or raises RequestShed/RequestRejected under backpressure)."""
         t0 = self._clock()
-        req = self._admit(x, deadline_ms, priority, now=t0, **kw)
-        # The closed check and the push share the cv lock with close():
-        # a request either lands before _closed is set (close's flush
-        # serves it) or is rejected — never silently stranded in a lane
-        # no worker will ever flush.
-        with self._cv:
-            if self._closed:
-                req.fail(RequestRejected("scheduler is closed"))
-                return req.future
-            action = self.queue.push(req)
-            self.counters["submitted"] += 1
-            self._cv.notify()
-        if OBS.enabled:
-            OBS_A.record_admit(self, req, action, t0, self._clock())
+        with span("admit") if OBS.enabled else NULL_SPAN as sp:
+            req = self._admit(x, deadline_ms, priority, now=t0, **kw)
+            # The closed check and the push share the cv lock with
+            # close(): a request either lands before _closed is set
+            # (close's flush serves it) or is rejected — never silently
+            # stranded in a lane no worker will ever flush.
+            with self._cv:
+                if self._closed:
+                    req.fail(RequestRejected("scheduler is closed"))
+                    return req.future
+                action = self.queue.push(req)
+                self.counters["submitted"] += 1
+                self._cv.notify()
+            if OBS.enabled:
+                OBS_A.record_admit(self, req, action, sp)
         return req.future
 
     def close(self, wait: bool = True) -> None:
@@ -274,16 +286,22 @@ class _BucketScheduler:
         """One scheduling decision: flush the most urgent ready lane, or
         materialize one in-flight bucket.  Returns False when idle.
         (The worker thread loops this; tests drive it directly.)"""
-        sel = self._select_flush(self._clock())
-        if sel is not None:
-            key, reason, force = sel
-            reqs = self.queue.take(key, self.max_batch,
-                                   self._bucket_key,
-                                   min_fill=self.cfg.min_fill, force=force)
-            if reqs:
-                self.counters[f"flush_{reason}"] += 1
-                self._dispatch_safe(reqs, reason)
-                return True
+        with span("select", bid=self._bid) if OBS.enabled \
+                else NULL_SPAN as sp:
+            sel = self._select_flush(self._clock())
+            reqs = None
+            if sel is not None:
+                key, reason, force = sel
+                reqs = self.queue.take(key, self.max_batch,
+                                       self._bucket_key,
+                                       min_fill=self.cfg.min_fill,
+                                       force=force)
+            if OBS.enabled:
+                sp.set(reason=reason if reqs else None)
+        if reqs:
+            self.counters[f"flush_{reason}"] += 1
+            self._dispatch_safe(reqs, reason)
+            return True
         return self._drain_one()
 
     def _dispatch_safe(self, reqs: list, reason: str) -> None:
@@ -291,10 +309,14 @@ class _BucketScheduler:
         the engine fails THIS bucket's futures and the loop lives on
         (a shape-mismatched input would otherwise strand every pending
         future behind a dead daemon thread)."""
+        bucket = NULL_SPAN
         if OBS.enabled:
-            OBS_A.record_bucket(self, reqs, reason, self._clock())
+            bid, self._bid = self._bid, self._bid + 1
+            OBS_A.record_bucket(self, reqs, reason, self._clock(), bid)
+            bucket = OBS.tracer.bucket(bid)
         try:
-            self._dispatch(reqs, reason)
+            with bucket:
+                self._dispatch(reqs, reason)
         except Exception as e:                     # noqa: BLE001
             if self._on_dispatch_error(reqs, e):
                 return                             # re-routed, not failed
@@ -329,24 +351,41 @@ class _BucketScheduler:
             pass
 
     def _run(self) -> None:
+        try:
+            self._loop()
+        finally:
+            OBS.tracer.untile()
+
+    def _loop(self) -> None:
+        busy = False
         while True:
-            with self._cv:
-                if not self._stop:
-                    busy = not self.queue.empty
-                    self._cv.wait(self._wait_timeout(self._clock())
-                                  if busy else
-                                  (0.002 if self._has_inflight() else None))
-                if self._stop:
-                    return
-            try:
-                while self.pump():
+            if OBS.enabled:
+                # each turn: obs can be switched on while the loop runs
+                OBS.tracer.tile()
+            if not busy:
+                with span("wait", bid=self._bid) if OBS.enabled \
+                        else NULL_SPAN as sp, self._cv:
+                    if not self._stop:
+                        queued = not self.queue.empty
+                        timeout = self._wait_timeout(self._clock()) \
+                            if queued else \
+                            (0.002 if self._has_inflight() else None)
+                        if OBS.enabled:
+                            sp.set(why="timer" if queued else
+                                   "inflight-poll" if timeout else "empty")
+                        self._cv.wait(timeout)
                     if self._stop:
                         return
+            try:
+                busy = self.pump()
+                if busy and self._stop:
+                    return
             except Exception as e:                 # noqa: BLE001
                 # Dispatch errors are contained by _dispatch_safe; this
                 # catches scheduler bugs so the thread survives (queued
                 # work still fails fast through _dispatch_safe rather
                 # than hanging behind a dead loop).
+                busy = False
                 self.last_error = e
                 OBS_LOG.error("scheduler", "scheduler loop error",
                               exc=e, scheduler=type(self).__name__)
@@ -481,8 +520,9 @@ class AsyncDartServer(_BucketScheduler):
                                   min_exit=min_exit))
 
     def _dispatch(self, reqs: list, reason: str) -> None:
-        x = np.concatenate([r.x for r in reqs])
-        alpha = np.concatenate([r.alpha for r in reqs])
+        with span("gather") if OBS.enabled else NULL_SPAN:
+            x = np.concatenate([r.x for r in reqs])
+            alpha = np.concatenate([r.alpha for r in reqs])
         t0 = self._clock()
         out = self._infer_batch(reqs, x, alpha)
         # Service EMA from the dispatch call itself: it feeds the
@@ -493,7 +533,8 @@ class AsyncDartServer(_BucketScheduler):
         service = self._clock() - t0
         self._service_s = service if not self._service_s else \
             0.8 * self._service_s + 0.2 * service
-        self._inflight.append((reqs, out, t0))
+        self._inflight.append((reqs, out, t0,
+                               OBS.tracer.bid if OBS.enabled else None))
         while len(self._inflight) > self.cfg.pipeline_depth:
             self._complete_safe(*self._inflight.popleft())
 
@@ -503,9 +544,10 @@ class AsyncDartServer(_BucketScheduler):
         self._complete_safe(*self._inflight.popleft())
         return True
 
-    def _complete_safe(self, reqs, out, t_dispatch) -> None:
+    def _complete_safe(self, reqs, out, t_dispatch, bid=None) -> None:
         try:
-            self._complete(reqs, out, t_dispatch)
+            with OBS.tracer.bucket(bid) if OBS.enabled else NULL_SPAN:
+                self._complete(reqs, out, t_dispatch)
         except Exception as e:                     # noqa: BLE001
             self.last_error = e
             self.counters["complete_errors"] = \
@@ -523,40 +565,45 @@ class AsyncDartServer(_BucketScheduler):
 
     # -- completion -----------------------------------------------------
     def _complete(self, reqs, out, t_dispatch) -> None:
-        vals = {k: np.asarray(out[k]) for k in _RESULT_KEYS}
-        now = self._clock()
-        ends = np.cumsum([r.n for r in reqs])
-        lats, missed, results = [], [], []
-        for r, a, z in zip(reqs, np.concatenate([[0], ends[:-1]]), ends):
-            res = {k: v[a:z] for k, v in vals.items()}
-            lat_ms = (now - r.t_submit) * 1e3
-            miss = r.deadline_s is not None and now > r.deadline_s
-            res.update(latency_ms=lat_ms, deadline_missed=miss,
-                       predicted_cost=r.predicted_cost, lane=r.lane)
-            lats.append(lat_ms)
-            missed.append(miss)
-            results.append(res)
+        with span("fetch") if OBS.enabled else NULL_SPAN:
+            vals = {k: to_host(out[k], "output") for k in _RESULT_KEYS}
+            now = self._clock()
+            ends = np.cumsum([r.n for r in reqs])
+            lats, missed, results = [], [], []
+            for r, a, z in zip(reqs, np.concatenate([[0], ends[:-1]]),
+                               ends):
+                res = {k: v[a:z] for k, v in vals.items()}
+                lat_ms = (now - r.t_submit) * 1e3
+                miss = r.deadline_s is not None and now > r.deadline_s
+                res.update(latency_ms=lat_ms, deadline_missed=miss,
+                           predicted_cost=r.predicted_cost, lane=r.lane)
+                lats.append(lat_ms)
+                missed.append(miss)
+                results.append(res)
         # Telemetry folds BEFORE any future resolves: a caller woken by
         # fut.result() must find its request already in
         # stats()["requests"] (the documented pattern).
-        self.engine.record_requests(lats, missed)
-        self.planner.observe(vals["exit_idx"], vals["alpha"])
-        if self.predictor is not None:
-            self.predictor.observe(vals["alpha"], vals["exit_idx"])
-            self.engine.record_quotes(
-                [r.payload.get("quote_ms") for r in reqs], lats)
-            svc = getattr(self.planner, "observe_service", None)
-            if svc is not None:
-                svc((now - t_dispatch) * 1e3,
-                    float(np.mean(vals["exit_idx"])))
-        for r, res in zip(reqs, results):
-            self.daes.observe(r.lane, res["conf"], res["macs"],
-                              res["alpha"])
-        self.counters["completed"] += len(reqs)
-        if OBS.enabled:
-            OBS_A.record_completed(self, reqs, results, t_dispatch, now)
-        for r, res in zip(reqs, results):
-            r.resolve(res)
+        with span("fold") if OBS.enabled else NULL_SPAN:
+            self.engine.record_requests(lats, missed)
+            self.planner.observe(vals["exit_idx"], vals["alpha"])
+            if self.predictor is not None:
+                self.predictor.observe(vals["alpha"], vals["exit_idx"])
+                self.engine.record_quotes(
+                    [r.payload.get("quote_ms") for r in reqs], lats)
+                svc = getattr(self.planner, "observe_service", None)
+                if svc is not None:
+                    svc((now - t_dispatch) * 1e3,
+                        float(np.mean(vals["exit_idx"])))
+            for r, res in zip(reqs, results):
+                self.daes.observe(r.lane, res["conf"], res["macs"],
+                                  res["alpha"])
+        with span("resolve") if OBS.enabled else NULL_SPAN:
+            self.counters["completed"] += len(reqs)
+            if OBS.enabled:
+                OBS_A.record_completed(self, reqs, results, t_dispatch,
+                                       now)
+            for r, res in zip(reqs, results):
+                r.resolve(res)
 
     # -- metering -------------------------------------------------------
     def stats(self) -> dict:

@@ -30,6 +30,7 @@ import numpy as np
 
 from repro.core import adaptive as AD
 from repro.core import difficulty as DIFF
+from repro.obs import NULL_SPAN, OBS, span, to_host
 
 
 class AdmissionPlanner:
@@ -62,8 +63,15 @@ class AdmissionPlanner:
         """(alpha (n,), difficulty class, predicted cost/sample).
 
         ``engine._alpha`` routes through ``kernels.dispatch``, so
-        admission pays the fused difficulty kernel where available."""
-        alpha = np.asarray(self.engine._alpha(jnp.asarray(x)), np.float32)
+        admission pays the fused difficulty kernel where available.
+        With obs on, the copy of the images to the device is a ``put``
+        span and the read of alpha a ``sync`` span, children of the
+        caller's ``admit``."""
+        with span("put", bytes=int(x.nbytes)) if OBS.enabled \
+                else NULL_SPAN:
+            xd = jnp.asarray(x)
+        alpha = np.asarray(to_host(self.engine._alpha(xd), "admit_alpha"),
+                           np.float32)
         return (alpha,) + self.classify(alpha)
 
     def classify(self, alpha: np.ndarray):

@@ -21,7 +21,13 @@ Covers, per the PR 8 acceptance list:
   future silently;
 * continuous batching — slot spans carry slot ids, occupancy gauges
   export, and obs-on does not add compiled-step retraces
-  (``trace_counts`` stays 1 per key).
+  (``trace_counts`` stays 1 per key);
+* the dispatcher's phase spans, over a sharded engine — exclusive,
+  one ``bid`` per bucket, covering the dispatcher thread; every
+  blocking device→host read is a ``sync`` child of a phase (or of
+  ``admit``), exactly eight per masked bucket, counted by site;
+* the span API — parent, thread, inherited ``bid``, the profiler
+  annotation it enters — and the wall-clock Chrome export.
 """
 import json
 import logging
@@ -41,6 +47,7 @@ import repro.obs as obs
 from repro.core.routing import DartParams
 from repro.data.datasets import DatasetConfig, make_batch
 from repro.engine import DartEngine, LMDecodeEngine
+from repro.launch.mesh import make_serving_mesh
 from repro.models.transformer_lm import LMConfig, lm_init
 from repro.models.vit import ViTConfig, vit_init
 from repro.obs import metrics as M
@@ -201,17 +208,22 @@ def test_chrome_trace_tracks_per_lane(tmp_path):
 # disabled mode is inert; enabled mode reconciles
 # ---------------------------------------------------------------------------
 def test_disabled_inert_and_bit_identical(vit_engine_factory, eval_images):
-    assert not obs.is_enabled()
-    off, _, _ = _serve_stream(vit_engine_factory(), eval_images)
-    assert len(obs.get_tracer()) == 0
-    assert "dart_" not in obs.get_registry().render()
+    # the eager engine, then the sharded one (phase and sync spans)
+    for kw in ({}, {"mesh": make_serving_mesh()}):
+        obs.reset()
+        assert not obs.is_enabled()
+        off, _, _ = _serve_stream(vit_engine_factory(**kw), eval_images)
+        assert len(obs.get_tracer()) == 0
+        assert "dart_" not in obs.get_registry().render()
 
-    obs.configure(enabled=True)
-    on, _, _srv = _serve_stream(vit_engine_factory(), eval_images)
-    assert len(obs.get_tracer()) > 0
-    for a, b in zip(off, on):
-        for k in ("pred", "conf", "exit_idx", "alpha", "macs"):
-            assert np.array_equal(a[k], b[k]), k
+        obs.configure(enabled=True)
+        on, _, _srv = _serve_stream(vit_engine_factory(**kw), eval_images)
+        assert len(obs.get_tracer()) > 0
+        if kw:
+            assert obs.get_tracer().spans("sync")
+        for a, b in zip(off, on):
+            for k in ("pred", "conf", "exit_idx", "alpha", "macs"):
+                assert np.array_equal(a[k], b[k]), k
 
 
 def test_spans_reconcile_with_engine_telemetry(vit_engine_factory,
@@ -246,6 +258,191 @@ def test_spans_reconcile_with_engine_telemetry(vit_engine_factory,
     comp = sum(v for n, lab, v in
                fams["dart_requests_completed_total"]["samples"])
     assert comp == stats["scheduler"]["completed"] == n_req
+
+
+# ---------------------------------------------------------------------------
+# the dispatcher's phases and device->host syncs (sharded engine)
+# ---------------------------------------------------------------------------
+DISPATCHER = "AsyncDartServer"          # the dispatcher thread's name
+#: the masked path's blocking reads per bucket: five outputs in
+#: ``fetch``, three latency-ring reads in ``fold``
+SYNCS_PER_BUCKET = {("fetch", "output"): 5, ("fold", "latency_ring"): 3}
+
+
+@pytest.fixture(scope="module")
+def served_traced(vit_engine_factory, eval_images):
+    """One traced serve over a sharded engine: its spans and exposition
+    (obs is reset again before each test)."""
+    obs.reset()
+    obs.configure(enabled=True)
+    _, _, srv = _serve_stream(vit_engine_factory(mesh=make_serving_mesh()),
+                              eval_images)
+    out = {"spans": obs.get_tracer().spans(),
+           "fams": M.parse_prometheus(obs.get_registry().render()),
+           "n_req": len(eval_images) // 4}
+    del srv
+    obs.reset()
+    return out
+
+
+def _top_phases(spans, thread=None):
+    return sorted((s for s in spans if s["name"] in T.DISPATCH_PHASES
+                   and s["parent"] is None
+                   and thread in (None, s["thread"])),
+                  key=lambda s: s["ts"])
+
+
+def test_dispatcher_phases_share_a_bid_and_do_not_overlap(served_traced):
+    spans = served_traced["spans"]
+    phases = _top_phases(spans, DISPATCHER)
+    assert {"wait", "select", "gather", "put", "launch", "fetch", "fold",
+            "resolve"} <= {s["name"] for s in phases}
+    for a, b in zip(phases, phases[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + 1e-9, (a, b)
+    assert all(isinstance(s["bid"], int) for s in phases)
+    bids = [s["bid"] for s in spans if s["name"] == "bucket"]
+    assert bids == sorted(set(bids))
+    # every dispatched bucket went through each of its phases once,
+    # under its own bid (a bucket flushed at close runs on the closer)
+    for bid in bids:
+        names = sorted(s["name"] for s in _top_phases(spans)
+                       if s["bid"] == bid and s["name"] not in
+                       ("wait", "select"))
+        assert names == sorted(["gather", "put", "launch", "fetch",
+                                "fold", "resolve"]), (bid, names)
+
+
+def test_dispatcher_phases_cover_the_thread(served_traced):
+    phases = _top_phases(served_traced["spans"], DISPATCHER)
+    first = phases[0]["ts"]
+    last = max(s["ts"] + s["dur"] for s in phases)
+    covered = sum(s["dur"] for s in phases)
+    assert covered >= 0.95 * (last - first), covered / (last - first)
+    # each phase ends where the next begins; the time after its body
+    # (loop plumbing, the thread waiting to run) is its tail
+    for a, b in zip(phases, phases[1:]):
+        assert a["ts"] + a["dur"] == pytest.approx(b["ts"], abs=1e-9)
+    assert all(0.0 <= s["tail"] <= s["dur"] for s in phases)
+
+
+def test_every_sync_has_a_parent_phase(served_traced):
+    spans = served_traced["spans"]
+    syncs = [s for s in spans if s["name"] == "sync"]
+    assert syncs
+    for s in syncs:
+        assert s["parent"] in T.DISPATCH_PHASES + ("admit",), s
+        assert s["bytes"] > 0 and s["site"]
+    # admission: one copy to the device and one read of alpha, under
+    # its admit span, on the submitting thread
+    n_req = served_traced["n_req"]
+    for name, site in (("put", None), ("sync", "admit_alpha")):
+        kids = [s for s in spans if s["name"] == name
+                and s["parent"] == "admit"]
+        assert len(kids) == n_req
+        assert all(s.get("site") == site and s["thread"] != DISPATCHER
+                   for s in kids)
+
+
+def test_masked_path_syncs_per_bucket(served_traced):
+    spans = served_traced["spans"]
+    bids = [s["bid"] for s in spans if s["name"] == "bucket"]
+    for bid in bids:
+        got = {}
+        for s in spans:
+            if s["name"] == "sync" and s.get("bid") == bid:
+                key = (s["parent"], s["site"])
+                got[key] = got.get(key, 0) + 1
+        assert got == SYNCS_PER_BUCKET, (bid, got)
+    # the registry counts the same reads by site
+    fam = served_traced["fams"]["dart_device_syncs_total"]
+    by_site = {lab["site"]: v for _, lab, v in fam["samples"]}
+    assert by_site == {"output": 5.0 * len(bids),
+                       "latency_ring": 3.0 * len(bids),
+                       "admit_alpha": float(served_traced["n_req"])}
+
+
+def test_request_spans_carry_their_bucket(served_traced):
+    spans = served_traced["spans"]
+    rids_of = {s["bid"]: s["rids"] for s in spans if s["name"] == "bucket"}
+    assert sum(len(r) for r in rids_of.values()) == served_traced["n_req"]
+    for name in ("queue_wait", "compiled_step"):
+        got = [s for s in spans if s["name"] == name]
+        assert len(got) == served_traced["n_req"]
+        for s in got:
+            assert s["rid"] in rids_of[s["bid"]], s
+
+
+# ---------------------------------------------------------------------------
+# the span API and the wall-clock export
+# ---------------------------------------------------------------------------
+def test_span_records_parent_thread_and_bucket(monkeypatch):
+    entered = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    tr = T.Tracer()
+    with tr.bucket(7):
+        with tr.span("fetch", n=2) as sp:
+            with tr.span("sync", site="output"):
+                pass
+            sp.set(extra=1)
+        with tr.span("wait", bid=8):
+            pass
+    with tr.span("admit"):
+        pass
+    sync, fetch, wait, admit = tr.spans()
+    assert entered == ["fetch", "sync", "wait", "admit"]
+    assert (sync["parent"], sync["bid"], sync["site"]) == \
+        ("fetch", 7, "output")
+    assert fetch["parent"] is None and fetch["bid"] == 7
+    assert (fetch["n"], fetch["extra"]) == (2, 1)
+    assert fetch["ts"] <= sync["ts"] and \
+        sync["ts"] + sync["dur"] <= fetch["ts"] + fetch["dur"]
+    assert wait["bid"] == 8                  # an explicit bid wins
+    assert "bid" not in admit and tr.bid is None
+    assert {s["thread"] for s in tr.spans()} == {"MainThread"}
+
+
+def test_null_span_records_nothing():
+    with obs.NULL_SPAN as sp:
+        sp.set(anything=1)
+    assert obs.to_host(jnp.arange(3), "x").tolist() == [0, 1, 2]
+    assert len(obs.get_tracer()) == 0
+    assert "dart_device_syncs_total" not in obs.get_registry().render()
+
+
+def test_chrome_export_is_on_the_wall_clock(tmp_path):
+    tr = T.Tracer()
+    tr.wall_offset_ns = 5_000_000_000
+    with tr.span("gather", bid=0):
+        pass
+    tr.record("queue_wait", ts=1.0, dur=0.5, rid=0, lane=1)
+    path = tmp_path / "spans.jsonl"
+    assert tr.export_jsonl(str(path)) == 2
+    assert T.load_wall_offset_ns(str(path)) == 5_000_000_000
+    spans = T.load_jsonl(str(path))
+    assert [s["name"] for s in spans] == ["gather", "queue_wait"]
+    out = tmp_path / "trace.json"
+    res = subprocess.run(
+        [sys.executable, str(ROOT / "tools" / "trace_view.py"), str(path),
+         "-o", str(out)], capture_output=True, text=True, timeout=120)
+    assert res.returncode == 0, res.stderr
+    events = json.loads(out.read_text())["traceEvents"]
+    tracks = {e["args"]["name"] for e in events if e["ph"] == "M"}
+    assert tracks == {"thread MainThread", "lane 1"}
+    qw = next(e for e in events if e["name"] == "queue_wait")
+    assert qw["ts"] == pytest.approx(1.0e6 + 5.0e6)
+    g = next(e for e in events if e["name"] == "gather")
+    assert g["ts"] == pytest.approx(spans[0]["ts"] * 1e6 + 5.0e6)
 
 
 # ---------------------------------------------------------------------------

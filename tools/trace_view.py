@@ -7,9 +7,14 @@ Chrome ``trace_event`` format, loadable in Perfetto
 
     python tools/trace_view.py spans.jsonl -o spans.trace.json
 
-Spans land on one track per lane (difficulty class / cascade member /
-LM shape), so queue waits, compiled steps and exits line up visually
-per lane.  With no ``-o`` the JSON goes to stdout.
+Phase spans (the dispatcher's ``wait`` / ``select`` / ... /
+``resolve``, admission's ``admit``, with their ``sync`` children) land
+on one track per thread; request spans on one track per lane
+(difficulty class / cascade member / LM shape), so queue waits,
+compiled steps and exits line up visually per lane.  Timestamps are on
+the wall clock (the dump's ``wall_offset_ns`` header), so the file
+lines up with a profiler trace of the same run.  With no ``-o`` the
+JSON goes to stdout.
 """
 from __future__ import annotations
 
@@ -21,7 +26,7 @@ import sys
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent
                        / "src"))
 
-from repro.obs.trace import chrome_trace, load_jsonl
+from repro.obs.trace import chrome_trace, load_jsonl, load_wall_offset_ns
 
 
 def main(argv=None) -> int:
@@ -30,7 +35,7 @@ def main(argv=None) -> int:
     p.add_argument("-o", "--out", help="output path (default: stdout)")
     args = p.parse_args(argv)
     spans = load_jsonl(args.jsonl)
-    doc = chrome_trace(spans)
+    doc = chrome_trace(spans, load_wall_offset_ns(args.jsonl))
     text = json.dumps(doc)
     if args.out:
         pathlib.Path(args.out).write_text(text)
