@@ -72,10 +72,12 @@ class CascadePlanner:
 
     # -- admission ------------------------------------------------------
     def admit(self, x):
-        """(alpha (n,), lane=(member, class), predicted cascade cost)."""
+        """(alpha (n,), lane=(member, class), predicted cascade cost,
+        None): members are served from the host images (escalations
+        slice them), so no device rows are kept."""
         alpha = np.asarray(self.cascade._alpha(jnp.asarray(x)),
                            np.float32)
-        return (alpha,) + self.classify(alpha)
+        return (alpha,) + self.classify(alpha) + (None,)
 
     def classify(self, alpha):
         """(lane, cost) for a known alpha (degrade-alpha re-admission)."""

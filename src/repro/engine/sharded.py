@@ -108,6 +108,13 @@ class ShardedDartEngine(DartEngine):
         self.state = jax.device_put(owned, self._state_sh)
         self._steps: dict = {}        # cache key -> compiled callable
         self.trace_counts: dict = {}  # cache key -> number of traces
+        # Device rows: admission splits a request's images into one f32
+        # array per image (one program per request size), and a masked
+        # bucket is stacked from them on the device, zero images in its
+        # pad slots (one program per bucket size).
+        self.split_rows = jax.jit(self._split_traced)
+        self._stack = jax.jit(self._stack_traced, out_shardings=self._row)
+        self._zero_rows: dict = {}    # image shape -> device zero image
         # Host mirror of sum(state.since_update): checking the periodic-
         # update schedule must not force a device sync per request, or
         # back-to-back compiled steps could never pipeline.
@@ -133,6 +140,17 @@ class ShardedDartEngine(DartEngine):
     # ------------------------------------------------------------------
     # traced pieces
     # ------------------------------------------------------------------
+    def _split_traced(self, x):
+        """``split_rows``: the (n, ...) device batch ``x`` as n f32
+        device arrays, one per image, the form in which ``infer`` takes
+        a batch whose images are already on the device."""
+        self._count_trace(("split", x.shape[0]))
+        return tuple(x.astype(jnp.float32))
+
+    def _stack_traced(self, rows):
+        self._count_trace(("stack", len(rows)))
+        return jnp.stack(rows)
+
     def _coef_traced(self, state: EngineState):
         if self.adapt:
             # effective_coef touches only the shared (replicated) keys.
@@ -335,6 +353,10 @@ class ShardedDartEngine(DartEngine):
               min_exit: int = 0) -> dict:
         """Serve one request batch through the compiled path.
 
+        x — (B, ...) host images, or a tuple of B device images
+            (``split_rows``), which the compiled modes stack into the
+            padded batch on the device instead of padding, casting and
+            copying host images.
         mode="masked"    — one jitted step (serving hot path).
         mode="compacted" — compiled stage-segmented path (FLOP savings).
         mode="eager"     — the parent's eager masked pass (oracle;
@@ -362,8 +384,9 @@ class ShardedDartEngine(DartEngine):
             raise ValueError(
                 f"unknown mode {mode!r}; known: masked, compacted, eager")
         record = True if record is None else record
-        x = np.asarray(x)
-        b = x.shape[0]
+        if not isinstance(x, tuple):
+            x = np.asarray(x)
+        b = len(x)
         if b > self.compactor.max_bucket:
             parts = [self._infer_chunk(
                 x[a:z], mode, record,
@@ -380,26 +403,38 @@ class ShardedDartEngine(DartEngine):
         return out
 
     def _pad_batch(self, x, bp):
-        pad = self.compactor.pad(x.astype(np.float32, copy=False), bp)
+        """(x, valid) padded to ``bp`` rows on the row sharding: device
+        images stacked on the device with zero images in the pad slots,
+        host images padded and cast on the host and copied."""
         valid = np.zeros(bp, np.float32)
-        valid[:x.shape[0]] = 1.0
-        return (jax.device_put(jnp.asarray(pad), self._row),
-                jax.device_put(jnp.asarray(valid), self._row))
+        valid[:len(x)] = 1.0
+        if isinstance(x, tuple):
+            shape = x[0].shape
+            if shape not in self._zero_rows:
+                self._zero_rows[shape] = jnp.zeros(shape, jnp.float32)
+            xp = self._stack(x + (self._zero_rows[shape],) * (bp - len(x)))
+        else:
+            xp = jax.device_put(
+                self.compactor.pad(x.astype(np.float32, copy=False), bp),
+                self._row)
+        return xp, jax.device_put(valid, self._row)
 
     def _infer_chunk(self, x, mode, record, alpha=None,
                      min_exit: int = 0) -> dict:
-        b = x.shape[0]
+        b = len(x)
         bp = self.bucket_key(b)
         if mode == "masked":
-            # obs phases: ``put`` (host pad/cast + copies to the device),
-            # ``launch`` (the async step dispatch + output slicing)
+            # obs phases: ``put`` (the padded operands on the device;
+            # ``bytes``: what is copied from the host), ``launch`` (the
+            # async step dispatch + output slicing)
             with span("put") if OBS.enabled else NULL_SPAN as sp:
                 xp, valid = self._pad_batch(x, bp)
                 ap = None if alpha is None else jax.device_put(
-                    jnp.asarray(self.compactor.pad(
-                        np.asarray(alpha, np.float32), bp)), self._row)
+                    self.compactor.pad(np.asarray(alpha, np.float32), bp),
+                    self._row)
                 if OBS.enabled:
-                    sp.set(bytes=int(xp.nbytes + valid.nbytes) + (
+                    sp.set(bytes=int(valid.nbytes) + (
+                        0 if isinstance(x, tuple) else int(xp.nbytes)) + (
                         0 if ap is None else int(ap.nbytes)))
             with span("launch") if OBS.enabled else NULL_SPAN:
                 step = self._masked_step(bp, record, alpha is not None,
@@ -429,7 +464,7 @@ class ShardedDartEngine(DartEngine):
                 f"compacted mode needs a staged family; "
                 f"{type(self.cfg).__name__} is not staged — use "
                 f"mode='masked'")
-        b = x.shape[0]
+        b = len(x)
         xp, valid = self._pad_batch(x, bp)
         alpha = np.asarray(self._alpha(xp))[:b] if alpha is None \
             else np.asarray(alpha, np.float32)

@@ -84,6 +84,15 @@ def record_bucket(sched, reqs: list, reason: str, now: float,
                          ("reason",)).inc(1, reason=reason)
 
 
+def record_assembly(path: str) -> None:
+    """Where one dispatched bucket's images were assembled: ``device``
+    (stacked from admission's device rows) or ``host`` (concatenated
+    host images)."""
+    OBS.registry.counter("dart_bucket_assembly_total",
+                         "dispatched buckets by where their images were "
+                         "assembled", ("path",)).inc(1, path=path)
+
+
 def record_completed(server, reqs: list, results: list, t_dispatch: float,
                      now: float) -> None:
     """Completed requests of one materialized bucket: spans

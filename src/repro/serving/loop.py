@@ -36,11 +36,12 @@ pipeline is full or there is nothing left to dispatch.
 Tracing (obs on): the dispatcher thread's time is tiled by exclusive
 phase spans, each carrying the bucket id ``bid`` it works for —
 ``wait`` (``why``: empty / timer / inflight-poll), ``select`` (flush
-decision + take, ``reason``), ``gather``, ``put`` and ``launch`` (the
-engine's), ``fetch`` (output reads + per-request split), ``fold``
-(telemetry) and ``resolve`` (futures).  The time between two phases
-counts to the one that ended, as its ``tail`` (``Tracer.tile``).
-Blocking device→host reads are ``sync`` children
+decision + take, ``reason``), ``gather`` (``path``: ``device`` when the
+bucket is stacked from admission's device rows, else ``host``), ``put``
+and ``launch`` (the engine's), ``fetch`` (output reads + per-request
+split), ``fold`` (telemetry) and ``resolve`` (futures).  The time
+between two phases counts to the one that ended, as its ``tail``
+(``Tracer.tile``).  Blocking device→host reads are ``sync`` children
 (docs/observability.md).
 """
 from __future__ import annotations
@@ -56,6 +57,7 @@ import numpy as np
 
 from repro.core import daes as DAES
 from repro.core import difficulty as DIFF
+from repro.engine.sharded import ShardedDartEngine
 from repro.obs import NULL_SPAN, OBS, span, to_host
 from repro.obs import adapters as OBS_A
 from repro.obs import log as OBS_LOG
@@ -442,7 +444,12 @@ class AsyncDartServer(_BucketScheduler):
         super().__init__(cfg, clock=clock, start=start)
 
     def _make_planner(self, cfg: SchedulerConfig):
-        return AdmissionPlanner(self.engine, edges=cfg.edges)
+        # a sharded engine stacks masked buckets on the device from the
+        # rows admission already copied there
+        return AdmissionPlanner(
+            self.engine, edges=cfg.edges,
+            device_rows=cfg.mode == "masked"
+            and isinstance(self.engine, ShardedDartEngine))
 
     # -- hooks ----------------------------------------------------------
     def _bucket_key(self, n: int) -> int:
@@ -457,7 +464,7 @@ class AsyncDartServer(_BucketScheduler):
         x = np.asarray(x)
         if x.ndim == self.cfg.sample_ndim:
             x = x[None]
-        alpha, lane, cost = self.planner.admit(x)
+        alpha, lane, cost, rows = self.planner.admit(x)
         if self.cfg.policy == "degrade-alpha" \
                 and self.queue.depth(lane) >= self.cfg.max_queue:
             alpha = alpha * self.cfg.degrade_factor
@@ -491,7 +498,7 @@ class AsyncDartServer(_BucketScheduler):
             t_submit=now,
             deadline_s=None if deadline_ms is None
             else now + deadline_ms / 1e3,
-            future=Future(), payload=payload)
+            future=Future(), payload=payload, rows=rows)
 
     def _quote_ms(self, depth: float):
         quote_fn = getattr(self.planner, "quote_ms", None)
@@ -505,9 +512,9 @@ class AsyncDartServer(_BucketScheduler):
         bucket goes through unpadded (the sharded engine chunk-splits
         it; the eager forward just runs that shape) — bucket_key would
         raise BatchTooLarge on it."""
-        pad_to = self.engine.bucket_key(x.shape[0]) \
+        pad_to = self.engine.bucket_key(len(x)) \
             if self.cfg.mode == "masked" \
-            and x.shape[0] <= self.engine.compactor.max_bucket else None
+            and len(x) <= self.engine.compactor.max_bucket else None
         min_exit = 0
         if self.predictor is not None:
             # the bucket's smallest difficulty bounds every row (Eq. 19
@@ -520,9 +527,18 @@ class AsyncDartServer(_BucketScheduler):
                                   min_exit=min_exit))
 
     def _dispatch(self, reqs: list, reason: str) -> None:
-        with span("gather") if OBS.enabled else NULL_SPAN:
-            x = np.concatenate([r.x for r in reqs])
+        with span("gather") if OBS.enabled else NULL_SPAN as sp:
+            # admission's device rows where every request has them (the
+            # engine stacks them on the device), else the host images
+            if all(r.rows is not None for r in reqs):
+                x, path = tuple(row for r in reqs for row in r.rows), \
+                    "device"
+            else:
+                x, path = np.concatenate([r.x for r in reqs]), "host"
             alpha = np.concatenate([r.alpha for r in reqs])
+            if OBS.enabled:
+                sp.set(path=path)
+                OBS_A.record_assembly(path)
         t0 = self._clock()
         out = self._infer_batch(reqs, x, alpha)
         # Service EMA from the dispatch call itself: it feeds the

@@ -34,9 +34,15 @@ from repro.obs import NULL_SPAN, OBS, span, to_host
 
 
 class AdmissionPlanner:
+    """``device_rows``: admission also hands back the request's images
+    on the device, one f32 array per image (the engine's
+    ``split_rows``), for a scheduler whose engine stacks its buckets
+    there (a sharded engine serving masked buckets)."""
+
     def __init__(self, engine, edges=DIFF.DEFAULT_EDGES,
-                 ema_decay: float = 0.9):
+                 ema_decay: float = 0.9, device_rows: bool = False):
         self.engine = engine
+        self.device_rows = device_rows
         self.edges = np.asarray(edges, np.float32)
         self.n_classes = len(self.edges) + 1
         self.ema_decay = float(ema_decay)
@@ -60,19 +66,24 @@ class AdmissionPlanner:
 
     # ------------------------------------------------------------------
     def admit(self, x: np.ndarray):
-        """(alpha (n,), difficulty class, predicted cost/sample).
+        """(alpha (n,), difficulty class, predicted cost/sample, rows).
 
         ``engine._alpha`` routes through ``kernels.dispatch``, so
         admission pays the fused difficulty kernel where available.
-        With obs on, the copy of the images to the device is a ``put``
-        span and the read of alpha a ``sync`` span, children of the
-        caller's ``admit``."""
+        ``rows`` are the images already copied for it, kept on the
+        device with ``device_rows`` (None otherwise, and for a request
+        larger than the engine's largest bucket, which is served in
+        chunks from the host).  With obs on, the copy of the images to
+        the device is a ``put`` span and the read of alpha a ``sync``
+        span, children of the caller's ``admit``."""
         with span("put", bytes=int(x.nbytes)) if OBS.enabled \
                 else NULL_SPAN:
             xd = jnp.asarray(x)
+            rows = self.engine.split_rows(xd) if self.device_rows \
+                and len(x) <= self.engine.compactor.max_bucket else None
         alpha = np.asarray(to_host(self.engine._alpha(xd), "admit_alpha"),
                            np.float32)
-        return (alpha,) + self.classify(alpha)
+        return (alpha,) + self.classify(alpha) + (rows,)
 
     def classify(self, alpha: np.ndarray):
         """(difficulty class, predicted cost) for an already-known alpha

@@ -73,6 +73,12 @@ class Request:
     t_submit:       scheduler-clock seconds at submit
     deadline_s:     absolute scheduler-clock deadline (None = best effort)
     future:         resolves to the per-request result dict
+    rows:           the samples on the device, one f32 array per sample,
+                    made at admission for a dispatcher that stacks its
+                    buckets there (None: the dispatcher concatenates
+                    ``x`` on the host).  Dropped once the request fails
+                    or resolves, so a finished request holds no device
+                    memory
     """
     rid: int
     x: np.ndarray
@@ -85,11 +91,14 @@ class Request:
     deadline_s: float | None
     future: Future
     payload: dict = dataclasses.field(default_factory=dict)
+    rows: tuple | None = None
 
     def fail(self, exc: Exception) -> None:
+        self.rows = None
         if not self.future.done():
             self.future.set_exception(exc)
 
     def resolve(self, result: dict) -> None:
+        self.rows = None
         if not self.future.done():
             self.future.set_result(result)

@@ -510,10 +510,13 @@ class EnginePool:
     def note_example(self, x) -> None:
         """Record a dispatched batch shape so a joining engine can warm
         the same compiled buckets before taking traffic."""
-        x = np.asarray(x)
+        if isinstance(x, tuple):        # device rows: no read back
+            shape, dtype = (len(x),) + x[0].shape, x[0].dtype
+        else:
+            x = np.asarray(x)
+            shape, dtype = x.shape, x.dtype
         with self._lock:
-            self._warm_shapes.add(
-                (x.shape, str(x.dtype), self.warm_mode))
+            self._warm_shapes.add((shape, str(dtype), self.warm_mode))
 
     def _warm(self, eng) -> None:
         infer = getattr(eng, "infer", None)

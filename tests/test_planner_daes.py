@@ -80,7 +80,8 @@ def test_admit_alpha_matches_engine(engine):
     once, handed to dispatch."""
     pl = AdmissionPlanner(engine)
     x = np.asarray(jax.random.normal(jax.random.key(1), (4, 32, 32, 3)))
-    alpha, dclass, cost = pl.admit(x)
+    alpha, dclass, cost, rows = pl.admit(x)
+    assert rows is None                 # no device rows unless asked for
     np.testing.assert_allclose(
         alpha, np.asarray(engine._alpha(jnp.asarray(x))), atol=1e-6)
     assert dclass == int(DIFF.difficulty_class(float(alpha.mean()),
