@@ -1,9 +1,10 @@
-"""The main-path Pallas kernels compiled for a described TPU v5e.
+"""The main-path Pallas kernels, and ViT-H/14's masked serving forward,
+compiled for a described TPU v5e.
 
 The TPU compiler is installed even where no chip is attached: each test
-lowers and compiles a kernel for a ``v5e:2x2`` topology described in a
-fixture (nothing runs).  This catches what interpret mode cannot —
-block shapes off the (8, 128) tiling, scalar stores, and more scoped
+lowers and compiles a kernel or a forward for a ``v5e:2x2`` topology
+described in a fixture (nothing runs).  This catches what interpret mode
+cannot — block shapes off the (8, 128) tiling, scalar stores, and more scoped
 VMEM than a kernel may use — at serving widths, without chip time.
 
 The topology is described only inside the module fixture: loading the
@@ -14,8 +15,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, \
+    SingleDeviceSharding
 
 from repro.configs import registry
 from repro.kernels import dispatch
@@ -24,10 +27,13 @@ from repro.kernels.exit_gate.exit_gate_kernel import exit_gate_pallas
 from repro.kernels.exit_head.exit_head_kernel import exit_head_gate_pallas
 from repro.kernels.paged_gather.paged_gather_kernel import \
     paged_gather_pallas
+from repro.models import vit
+from repro.parallel.sharding import unzip
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 RESNET = registry.get("resnet-152")
 LLAMA = registry.get("tinyllama-1.1b")
+VITH = registry.get("vit-h14")
 SLOTS = 16                     # the continuous decoder's default pool
 
 
@@ -104,6 +110,40 @@ def test_paged_gather_compiles(one_chip):
         pg, tab, backend="pallas"), one_chip, pages,
         ((SLOTS, per_slot), I32))
     assert "tpu_custom_call" in hlo
+
+
+def test_vit_h14_masked_forward_fits_one_chip(one_chip):
+    """ViT-H/14's masked serving forward at the largest bucket, published
+    widths, bf16: every exit's logits and each exit's fused gate, sharded
+    over a one-chip ("data",) mesh as the serving step shards them; its
+    weights, activations and code fit the chip's 16 GB."""
+    bucket = 32
+    mesh = Mesh(np.array(list(one_chip.device_set)), ("data",))
+    rep, row = NamedSharding(mesh, PartitionSpec()), \
+        NamedSharding(mesh, PartitionSpec("data"))
+    shapes = jax.eval_shape(lambda k: unzip(vit.vit_init(k, VITH))[0],
+                            jax.random.key(0))
+    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=rep), shapes)
+    assert all(p.dtype == BF16 for p in jax.tree.leaves(params))
+
+    def forward(params, x, thresholds):
+        logits = vit.vit_forward(params, x, VITH)["exit_logits"]
+        return [dispatch.exit_gate(logits[i], thresholds[:, i],
+                                   backend="pallas", mesh=mesh, axis="data")
+                for i in range(VITH.n_exits)]
+
+    r = VITH.img_res
+    compiled = jax.jit(forward).lower(
+        params, jax.ShapeDtypeStruct((bucket, r, r, 3), F32, sharding=row),
+        jax.ShapeDtypeStruct((bucket, VITH.n_exits), F32, sharding=row),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes)
+    assert mem.argument_size_in_bytes > 2 * 600e6     # the bf16 weights
+    assert total < 16e9
 
 
 # ---------------------------------------------------------------------------
