@@ -74,7 +74,8 @@ class DartEngine:
                  adapt: bool = True, update_every: int = 100):
         self.cfg = model_cfg
         self.params = params
-        self.state = state
+        # request latency/deadline telemetry lives on the host
+        self.request_window, self.state = ST.RequestWindow.take(state)
         self.acfg = acfg
         self.dcfg = dcfg
         self.family = get_family(model_cfg)
@@ -495,14 +496,14 @@ class DartEngine:
 
     def record_requests(self, latencies_ms, missed=None) -> None:
         """Fold completed-request latency/deadline telemetry into the
-        engine state (host-side write; the async scheduler calls this
-        once per flushed bucket)."""
-        self.state = ST.record_requests(self.state, latencies_ms, missed)
+        engine's host-side request window (the async scheduler calls
+        this once per flushed bucket; no device access)."""
+        self.request_window.record_requests(latencies_ms, missed)
 
     def record_quotes(self, quotes_ms, realized_ms) -> None:
         """Fold admission-time SLO quote error telemetry (quote vs
-        realized latency; host-side write, like record_requests)."""
-        self.state = ST.record_quotes(self.state, quotes_ms, realized_ms)
+        realized latency) into the request window."""
+        self.request_window.record_quotes(quotes_ms, realized_ms)
 
     def stats(self) -> dict:
         """Serving counters + windowed §II.C statistics."""
@@ -516,20 +517,22 @@ class DartEngine:
         if out["served"]:
             w = AD.window_stats(s.adaptive, self.acfg)
             out["window"] = {k: np.asarray(v) for k, v in w.items()}
-        return OBS_STATS.attach_requests(out, s)
+        return OBS_STATS.attach_requests(out, self.request_window)
 
     # ------------------------------------------------------------------
     # state round-trip
     # ------------------------------------------------------------------
     def save_state(self, path: str, step: int = 0):
-        """Checkpoint the FULL serving state (one pytree) atomically."""
+        """Checkpoint the FULL serving state (one pytree, the request
+        window written into its leaves) atomically."""
         from repro import checkpoint as CK
-        return CK.save(path, step, self.state)
+        return CK.save(path, step, self.request_window.fill(self.state))
 
     def restore_state(self, path: str, step: int | None = None):
         # Pre-latency-telemetry checkpoints restore through the shared
-        # prefix migration (state.LEGACY_FIELDS).
-        self.state, step = ST.restore_with_migration(path, self.state, step)
+        # prefix migration (state.LEGACY_FIELDS), with an empty window.
+        state, step = ST.restore_with_migration(path, self.state, step)
+        self.request_window, self.state = ST.RequestWindow.take(state)
         self._policy_mirror = None
         return step
 

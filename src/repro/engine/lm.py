@@ -188,7 +188,9 @@ class LMDecodeEngine:
         acfg = AD.AdaptiveConfig(n_exits=self.n_exits,
                                  n_classes=min(cfg.vocab, 64))
         self.acfg = acfg
-        self.state = EngineState.create(self.n_exits, acfg, dart)
+        # request latency/deadline telemetry lives on the host
+        self.request_window, self.state = ST.RequestWindow.take(
+            EngineState.create(self.n_exits, acfg, dart))
 
         if mesh is not None:
             from repro.engine.sharded import _silence_donation_warning
@@ -320,10 +322,11 @@ class LMDecodeEngine:
     # ------------------------------------------------------------------
     def save_state(self, path: str, step: int = 0):
         from repro import checkpoint as CK
-        return CK.save(path, step, self.state)
+        return CK.save(path, step, self.request_window.fill(self.state))
 
     def restore_state(self, path: str, step: int | None = None):
-        self.state, step = ST.restore_with_migration(path, self.state, step)
+        state, step = ST.restore_with_migration(path, self.state, step)
+        self.request_window, self.state = ST.RequestWindow.take(state)
         self._policy_mirror = None
         if self.mesh is not None:
             self._commit()
@@ -348,33 +351,18 @@ class LMDecodeEngine:
                 "slot_steps": int(tel["slot_steps"]),
                 "decode_steps": int(tel["decode_steps"]),
                 "pages_peak": int(np.asarray(self.state.pages_peak))})
-        return OBS_STATS.attach_requests(out, self.state)
+        return OBS_STATS.attach_requests(out, self.request_window)
 
     def record_requests(self, latencies_ms, missed=None) -> None:
         """Fold completed-request latency/deadline telemetry into the
-        engine state (host-side write; the LM session calls this once
-        per flushed decode bucket)."""
-        self.state = ST.record_requests(self.state, latencies_ms, missed)
-        if self.mesh is not None:
-            s = self.state
-            self.state = dataclasses.replace(
-                s, lat_ms=jax.device_put(s.lat_ms, self._repl),
-                lat_ptr=jax.device_put(s.lat_ptr, self._repl),
-                lat_count=jax.device_put(s.lat_count, self._repl),
-                deadline_miss=jax.device_put(s.deadline_miss, self._repl))
+        engine's host-side request window (the LM session calls this
+        once per flushed decode bucket)."""
+        self.request_window.record_requests(latencies_ms, missed)
 
     def record_quotes(self, quotes_ms, realized_ms) -> None:
         """Fold admission-time SLO quote error telemetry (quote vs
-        realized latency; host-side write, like record_requests)."""
-        self.state = ST.record_quotes(self.state, quotes_ms, realized_ms)
-        if self.mesh is not None:
-            s = self.state
-            self.state = dataclasses.replace(
-                s, quote_ms_sum=jax.device_put(s.quote_ms_sum,
-                                               self._repl),
-                quote_err_ms_sum=jax.device_put(s.quote_err_ms_sum,
-                                                self._repl),
-                quote_count=jax.device_put(s.quote_count, self._repl))
+        realized latency) into the request window."""
+        self.request_window.record_quotes(quotes_ms, realized_ms)
 
     def _count_trace(self, key):
         # Runs in the Python body of a step function, i.e. once per trace.

@@ -565,26 +565,6 @@ class ShardedDartEngine(DartEngine):
         self._commit()
         return pol
 
-    def record_requests(self, latencies_ms, missed=None) -> None:
-        super().record_requests(latencies_ms, missed)
-        # Re-pin the freshly host-written latency leaves so the next
-        # donated step sees the same (replicated) layout every time.
-        s = self.state
-        self.state = dataclasses.replace(
-            s, lat_ms=jax.device_put(s.lat_ms, self._repl),
-            lat_ptr=jax.device_put(s.lat_ptr, self._repl),
-            lat_count=jax.device_put(s.lat_count, self._repl),
-            deadline_miss=jax.device_put(s.deadline_miss, self._repl))
-
-    def record_quotes(self, quotes_ms, realized_ms) -> None:
-        super().record_quotes(quotes_ms, realized_ms)
-        s = self.state
-        self.state = dataclasses.replace(
-            s, quote_ms_sum=jax.device_put(s.quote_ms_sum, self._repl),
-            quote_err_ms_sum=jax.device_put(s.quote_err_ms_sum,
-                                            self._repl),
-            quote_count=jax.device_put(s.quote_count, self._repl))
-
     def restore_state(self, path: str, step: int | None = None):
         step = super().restore_state(path, step)
         self._pending = int(np.sum(np.asarray(self.state.since_update)))
@@ -605,4 +585,4 @@ class ShardedDartEngine(DartEngine):
         if out["served"]:
             w = AD.window_stats(ST.merged_adaptive(self.state), self.acfg)
             out["window"] = {k: np.asarray(v) for k, v in w.items()}
-        return OBS_STATS.attach_requests(out, self.state)
+        return OBS_STATS.attach_requests(out, self.request_window)

@@ -14,6 +14,7 @@ stored as 0-d arrays so the state round-trips through
 from __future__ import annotations
 
 import dataclasses
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +22,6 @@ import numpy as np
 
 from repro.core import adaptive as AD
 from repro.core.routing import DartParams
-from repro.obs import to_host
 
 _FIELDS = ("tau", "coef", "beta_diff", "beta_opt", "adaptive",
            "served", "exit_counts", "total_macs", "since_update",
@@ -61,20 +61,22 @@ class EngineState:
     exit_counts:  (E,) int32 — per-exit routed counts
     total_macs:   () float32 — cumulative MACs actually spent
     since_update: () int32 — samples since the last periodic update
-    lat_ms:       (W,) float32 — per-REQUEST latency ring buffer, written
-                  host-side by the ``repro.serving`` scheduler
+    lat_ms:       (W,) float32 — per-REQUEST latency ring buffer
     lat_ptr:      () int32 — latency ring write cursor
     lat_count:    () int32 — requests completed (lifetime)
     deadline_miss: () int32 — requests completed past their deadline
+                  (these four and the three ``quote_*`` leaves are the
+                  checkpoint image of the engine's host-side
+                  :class:`RequestWindow`; empty in a live engine's state)
     slot_steps:   () int32 — continuous batching: occupied slot-steps
                   (sum over decode steps of active slots; folded on
                   device inside the compiled step)
     decode_steps: () int32 — continuous batching: compiled decode-step
                   launches
     pages_peak:   () int32 — continuous batching: peak KV pages in use
-                  (host-written at admission, like the latency window)
+                  (host-written at admission)
     quote_ms_sum: () float32 — sum of admission-time latency quotes for
-                  completed quoted requests (host-written)
+                  completed quoted requests
     quote_err_ms_sum: () float32 — sum of |quote - realized latency|
                   over the same requests (the SLO quote error)
     quote_count:  () int32 — completed requests that carried a quote
@@ -167,86 +169,124 @@ jax.tree_util.register_pytree_node(
 # ---------------------------------------------------------------------------
 # Per-request serving telemetry (latency / deadline SLO)
 # ---------------------------------------------------------------------------
-# Unlike the per-SAMPLE counters above (folded on device inside the
-# compiled step), request latency is a host-side quantity — the clock
-# starts at submit() and stops when the scheduler materializes the
-# result — so these two helpers run eagerly on numpy and the scheduler
-# folds the outcome back into the state between steps.  The leaves stay
-# replicated under sharding (one global latency window per engine).
+class RequestWindow:
+    """Per-request latency/deadline telemetry, kept on the host.
 
-def record_requests(state: EngineState, latencies_ms,
-                    missed=None) -> EngineState:
-    """Fold a batch of completed requests into the latency ring buffer.
+    Unlike the per-SAMPLE counters above (folded on device inside the
+    compiled step), request latency is a host-side quantity: the clock
+    starts at submit() and stops when the scheduler materializes the
+    result.  So the window lives in numpy: a ring of the last W request
+    latencies, lifetime request and deadline-miss counts, and the sums
+    of admission-time latency quotes.  The scheduler writes it once per
+    completed bucket without touching the device, so completing a bucket
+    never waits for the step launched after it.
 
-    latencies_ms: (k,) per-request wall latency; ``missed``: optional
-    (k,) bools — completed after the request's deadline."""
-    lat = np.atleast_1d(np.asarray(latencies_ms, np.float32))
-    k, w = lat.shape[0], state.lat_ms.shape[0]
-    if k == 0:
-        return state
-    # three blocking reads of the newest state (obs ``sync`` spans)
-    buf = to_host(state.lat_ms, "latency_ring").copy()
-    idx = (int(to_host(state.lat_ptr, "latency_ring")) + np.arange(k)) % w
-    buf[idx] = lat
-    n_miss = int(np.sum(missed)) if missed is not None else 0
-    return dataclasses.replace(
-        state,
-        lat_ms=jnp.asarray(buf),
-        lat_ptr=jnp.asarray(
-            (int(to_host(state.lat_ptr, "latency_ring")) + k) % w,
-            jnp.int32),
-        lat_count=state.lat_count + jnp.asarray(k, jnp.int32),
-        deadline_miss=state.deadline_miss + jnp.asarray(n_miss, jnp.int32))
+    Every engine owns one (``engine.request_window``).  The EngineState
+    leaves ``lat_*``, ``deadline_miss`` and ``quote_*`` hold it only in
+    checkpoints:
+    :meth:`fill` writes it into the state an engine saves, :meth:`take`
+    reads it back out of a restored one.  One dispatcher thread writes,
+    any thread may read (``stats``), under the window's lock."""
 
+    def __init__(self, size: int = LAT_WINDOW):
+        self.lat_ms = np.zeros(size, np.float32)
+        self.ptr = 0
+        self.count = 0
+        self.deadline_miss = 0
+        self.quote_ms_sum = np.float32(0.0)
+        self.quote_err_ms_sum = np.float32(0.0)
+        self.quote_count = 0
+        self._lock = threading.Lock()
 
-def record_quotes(state: EngineState, quotes_ms,
-                  realized_ms) -> EngineState:
-    """Fold admission-time latency quotes vs realized latency for a
-    batch of completed requests (host-side, like the latency window).
-    Entries with a None/NaN quote (admitted before the service EMA
-    seeded) are skipped."""
-    q = np.asarray([np.nan if v is None else v for v in quotes_ms],
-                   np.float32)
-    r = np.asarray(realized_ms, np.float32)
-    ok = ~np.isnan(q)
-    k = int(ok.sum())
-    if k == 0:
-        return state
-    return dataclasses.replace(
-        state,
-        quote_ms_sum=state.quote_ms_sum
-        + jnp.asarray(float(q[ok].sum()), jnp.float32),
-        quote_err_ms_sum=state.quote_err_ms_sum
-        + jnp.asarray(float(np.abs(q[ok] - r[ok]).sum()), jnp.float32),
-        quote_count=state.quote_count + jnp.asarray(k, jnp.int32))
+    def record_requests(self, latencies_ms, missed=None) -> None:
+        """Fold a batch of completed requests into the ring.
+
+        latencies_ms: (k,) per-request wall latency; ``missed``: optional
+        (k,) bools — completed after the request's deadline."""
+        lat = np.atleast_1d(np.asarray(latencies_ms, np.float32))
+        k, w = lat.shape[0], self.lat_ms.shape[0]
+        if k == 0:
+            return
+        n_miss = int(np.sum(missed)) if missed is not None else 0
+        with self._lock:
+            self.lat_ms[(self.ptr + np.arange(k)) % w] = lat
+            self.ptr = (self.ptr + k) % w
+            self.count += k
+            self.deadline_miss += n_miss
+
+    def record_quotes(self, quotes_ms, realized_ms) -> None:
+        """Fold admission-time latency quotes vs realized latency for a
+        batch of completed requests.  Entries with a None/NaN quote
+        (admitted before the service EMA seeded) are skipped.  The sums
+        accumulate in float32, as the checkpointed leaves do."""
+        q = np.asarray([np.nan if v is None else v for v in quotes_ms],
+                       np.float32)
+        r = np.asarray(realized_ms, np.float32)
+        ok = ~np.isnan(q)
+        k = int(ok.sum())
+        if k == 0:
+            return
+        with self._lock:
+            self.quote_ms_sum += q[ok].sum()
+            self.quote_err_ms_sum += np.abs(q[ok] - r[ok]).sum()
+            self.quote_count += k
+
+    def stats(self) -> dict:
+        """Windowed latency percentiles + lifetime deadline-miss rate
+        (and the mean quote and quote error once a quote completed)."""
+        with self._lock:
+            n, miss, qn = self.count, self.deadline_miss, self.quote_count
+            lat = self.lat_ms[:min(n, self.lat_ms.shape[0])].copy()
+            q_sum = float(self.quote_ms_sum)
+            q_err = float(self.quote_err_ms_sum)
+        out = {"requests": n, "deadline_miss": miss,
+               "miss_rate": miss / max(n, 1)}
+        if n:
+            out["latency_ms"] = latency_percentiles(lat)
+        if qn:
+            out["quote"] = {"quoted": qn, "mean_quote_ms": q_sum / qn,
+                            "mean_abs_err_ms": q_err / qn}
+        return out
+
+    def fill(self, state: EngineState) -> EngineState:
+        """``state`` with this window in its ``lat_*``,
+        ``deadline_miss`` and ``quote_*`` leaves (what a checkpoint
+        saves)."""
+        with self._lock:
+            return dataclasses.replace(
+                state, lat_ms=jnp.asarray(self.lat_ms.copy()),
+                lat_ptr=jnp.asarray(self.ptr, jnp.int32),
+                lat_count=jnp.asarray(self.count, jnp.int32),
+                deadline_miss=jnp.asarray(self.deadline_miss, jnp.int32),
+                quote_ms_sum=jnp.asarray(self.quote_ms_sum, jnp.float32),
+                quote_err_ms_sum=jnp.asarray(self.quote_err_ms_sum,
+                                             jnp.float32),
+                quote_count=jnp.asarray(self.quote_count, jnp.int32))
+
+    @classmethod
+    def take(cls, state: EngineState):
+        """``(window, state)``: the window ``state``'s leaves hold, and
+        ``state`` with those leaves emptied — the live state never
+        carries the window, so it cannot go stale there."""
+        win = cls(state.lat_ms.shape[0])
+        win.lat_ms[:] = np.asarray(state.lat_ms, np.float32)
+        win.ptr = int(state.lat_ptr)
+        win.count = int(state.lat_count)
+        win.deadline_miss = int(state.deadline_miss)
+        win.quote_ms_sum = np.float32(state.quote_ms_sum)
+        win.quote_err_ms_sum = np.float32(state.quote_err_ms_sum)
+        win.quote_count = int(state.quote_count)
+        return win, cls(win.lat_ms.shape[0]).fill(state)
 
 
 def latency_percentiles(lat_ms) -> dict:
     """p50/p95/p99/mean summary of a latency sample (ms).  The one
     implementation behind every ``stats()["requests"]["latency_ms"]``
-    report (engine request_stats, LM decode sessions)."""
+    report (``RequestWindow.stats``)."""
     lat = np.asarray(lat_ms, np.float32)
     p50, p95, p99 = np.percentile(lat, [50.0, 95.0, 99.0])
     return {"p50": float(p50), "p95": float(p95), "p99": float(p99),
             "mean": float(lat.mean())}
-
-
-def request_stats(state: EngineState) -> dict:
-    """Windowed latency percentiles + lifetime deadline-miss rate."""
-    n = int(state.lat_count)
-    miss = int(state.deadline_miss)
-    out = {"requests": n, "deadline_miss": miss,
-           "miss_rate": miss / max(n, 1)}
-    if n:
-        out["latency_ms"] = latency_percentiles(
-            np.asarray(state.lat_ms)[:min(n, state.lat_ms.shape[0])])
-    qn = int(state.quote_count)
-    if qn:
-        out["quote"] = {
-            "quoted": qn,
-            "mean_quote_ms": float(state.quote_ms_sum) / qn,
-            "mean_abs_err_ms": float(state.quote_err_ms_sum) / qn}
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -309,8 +349,8 @@ def state_shardings(state: EngineState, repl, row) -> EngineState:
         served=row, exit_counts=row, total_macs=row, since_update=row,
         slot_steps=row, decode_steps=row,
         # host-written telemetry: one global value per engine (no
-        # replica axis) — the latency window, the page high-watermark
-        # and the admission-quote error counters
+        # replica axis) — the request window's checkpoint image and the
+        # page high-watermark
         lat_ms=repl, lat_ptr=repl, lat_count=repl, deadline_miss=repl,
         pages_peak=repl,
         quote_ms_sum=repl, quote_err_ms_sum=repl, quote_count=repl)

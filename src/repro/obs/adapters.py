@@ -84,13 +84,18 @@ def record_bucket(sched, reqs: list, reason: str, now: float,
                          ("reason",)).inc(1, reason=reason)
 
 
-def record_assembly(path: str) -> None:
+def record_assembly(path: str, behind: int) -> None:
     """Where one dispatched bucket's images were assembled: ``device``
     (stacked from admission's device rows) or ``host`` (concatenated
-    host images)."""
+    host images); and how many buckets were still running on the
+    device when it launched (``behind``)."""
     OBS.registry.counter("dart_bucket_assembly_total",
                          "dispatched buckets by where their images were "
                          "assembled", ("path",)).inc(1, path=path)
+    OBS.registry.counter("dart_buckets_launched_total",
+                         "dispatched buckets by the number of earlier "
+                         "buckets whose outputs were not ready yet",
+                         ("behind",)).inc(1, behind=str(behind))
 
 
 def record_completed(server, reqs: list, results: list, t_dispatch: float,
@@ -348,19 +353,17 @@ def _collect_scheduler(reg, sched, name: str) -> None:
                       "fraction of requests whose predicted depth band "
                       "matched the realized exit").set(ps["hit_rate"])
         # admission-quote error (quote vs realized latency), from the
-        # EngineState quote counters
-        est = getattr(sched, "engine", None)
-        if est is not None:
-            qs = est.state
-            qn = int(np.asarray(qs.quote_count))
-            if qn:
-                reg.gauge("dart_quote_mean_abs_err_ms",
-                          "mean |admission quote - realized latency|"
-                          ).set(float(np.asarray(qs.quote_err_ms_sum))
-                                / qn)
-                reg.gauge("dart_quote_mean_ms",
-                          "mean admission-time latency quote").set(
-                    float(np.asarray(qs.quote_ms_sum)) / qn)
+        # engine's request window
+        win = getattr(getattr(sched, "engine", None), "request_window",
+                      None)
+        quote = win.stats().get("quote") if win is not None else None
+        if quote is not None:
+            reg.gauge("dart_quote_mean_abs_err_ms",
+                      "mean |admission quote - realized latency|"
+                      ).set(quote["mean_abs_err_ms"])
+            reg.gauge("dart_quote_mean_ms",
+                      "mean admission-time latency quote").set(
+                quote["mean_quote_ms"])
 
     # engine telemetry (after the reduce_telemetry fold inside stats())
     engine = getattr(sched, "engine", None)

@@ -8,8 +8,8 @@ started to drift.  They now all call:
     tel = ST.telemetry_totals(self.state, sharded=...)   # ONE reduction
     out = OBS_STATS.engine_summary(tel)                  # ONE key set
     ...engine-specific extras...
-    return OBS_STATS.attach_requests(out, self.state)    # ONE percentile
-                                                         # implementation
+    # ONE percentile implementation, over the host request window:
+    return OBS_STATS.attach_requests(out, self.request_window)
 
 so key naming cannot drift again, and the obs adapters (which join the
 tracer's host-side spans against exactly these reductions) read one
@@ -39,12 +39,12 @@ def engine_summary(telemetry: dict) -> dict:
             "mean_macs": total_macs / max(served, 1)}
 
 
-def attach_requests(out: dict, state) -> dict:
-    """Attach the latency-ring percentiles/miss-rate block (if any
-    requests were recorded) — the single percentile implementation is
-    :func:`repro.engine.state.latency_percentiles`."""
-    from repro.engine import state as ST
-    req = ST.request_stats(state)
+def attach_requests(out: dict, window) -> dict:
+    """Attach the request window's percentiles/miss-rate block (if any
+    requests were recorded; ``window`` is the engine's
+    :class:`repro.engine.state.RequestWindow`) — the single percentile
+    implementation is :func:`repro.engine.state.latency_percentiles`."""
+    req = window.stats()
     if req["requests"]:
         out["requests"] = req
     return out

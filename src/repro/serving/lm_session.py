@@ -105,9 +105,9 @@ class LMDecodeSession(_BucketScheduler):
             lats.append(lat_ms)
             missed.append(miss)
             slices.append(stages[a:z])
-        # latency/deadline telemetry folds into the EngineState — the
-        # ONE store behind both session.stats() and engine.stats()
-        # (and it checkpoints with the engine)
+        # latency/deadline telemetry folds into the engine's request
+        # window — the ONE store behind both session.stats() and
+        # engine.stats() (and it checkpoints with the engine)
         self.engine.record_requests(lats, missed)
         if self.predictor is not None:
             # realized depth per row = mean decode exit stage
@@ -127,11 +127,10 @@ class LMDecodeSession(_BucketScheduler):
 
     # -- metering -------------------------------------------------------
     def stats(self) -> dict:
-        from repro.engine.state import request_stats
         out = {"scheduler": {**self.counters, "shed": self.queue.shed,
                              "rejected": self.queue.rejected,
                              "starved": self.queue.starved},
-               "requests": request_stats(self.engine.state),
+               "requests": self.engine.request_window.stats(),
                "exit_hist": np.asarray(self.engine.stats_exit).tolist(),
                "layers_run": self.engine.layers_run,
                "layers_skipped": self.engine.layers_skipped}

@@ -30,14 +30,18 @@ Flush policy (size-or-deadline):
 Pipelining: with a sharded engine, dispatched outputs stay ON DEVICE
 (PR 2 left them lazy precisely for this) — the loop keeps up to
 ``pipeline_depth`` buckets in flight and only materializes (resolving
-futures, folding latency telemetry into ``EngineState``) when the
-pipeline is full or there is nothing left to dispatch.
+futures, folding latency telemetry into the engine's host-side request
+window) when the pipeline is full or there is nothing left to
+dispatch.  Completing a bucket reads only that bucket's outputs, never
+the engine's state, so the buckets launched after it stay queued on
+the device while the host works.
 
 Tracing (obs on): the dispatcher thread's time is tiled by exclusive
 phase spans, each carrying the bucket id ``bid`` it works for —
 ``wait`` (``why``: empty / timer / inflight-poll), ``select`` (flush
 decision + take, ``reason``), ``gather`` (``path``: ``device`` when the
-bucket is stacked from admission's device rows, else ``host``), ``put``
+bucket is stacked from admission's device rows, else ``host``;
+``behind``: in-flight buckets whose outputs are not ready yet), ``put``
 and ``launch`` (the engine's), ``fetch`` (output reads + per-request
 split), ``fold`` (telemetry) and ``resolve`` (futures).  The time
 between two phases counts to the one that ended, as its ``tail``
@@ -53,6 +57,7 @@ import time
 from collections import deque
 from concurrent.futures import Future
 
+import jax
 import numpy as np
 
 from repro.core import daes as DAES
@@ -68,6 +73,14 @@ from repro.serving.request import DispatchError, Request, RequestRejected
 
 #: result keys sliced per request out of a consolidated engine call
 _RESULT_KEYS = ("pred", "conf", "exit_idx", "alpha", "macs")
+
+
+def _ready(out: dict) -> bool:
+    """Whether a dispatched bucket's outputs are computed, without
+    blocking: its first output leaf's ``is_ready()`` (host values are
+    ready)."""
+    leaf = jax.tree.leaves(out)[0]
+    return not isinstance(leaf, jax.Array) or leaf.is_ready()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -537,8 +550,10 @@ class AsyncDartServer(_BucketScheduler):
                 x, path = np.concatenate([r.x for r in reqs]), "host"
             alpha = np.concatenate([r.alpha for r in reqs])
             if OBS.enabled:
-                sp.set(path=path)
-                OBS_A.record_assembly(path)
+                behind = sum(not _ready(out) for _, out, _, _ in
+                             self._inflight)
+                sp.set(path=path, behind=behind)
+                OBS_A.record_assembly(path, behind)
         t0 = self._clock()
         out = self._infer_batch(reqs, x, alpha)
         # Service EMA from the dispatch call itself: it feeds the
@@ -624,7 +639,8 @@ class AsyncDartServer(_BucketScheduler):
     # -- metering -------------------------------------------------------
     def stats(self) -> dict:
         """Engine stats (incl. ``requests`` latency percentiles + miss
-        rate, folded into EngineState) + scheduler-level counters."""
+        rate, from the engine's request window) + scheduler-level
+        counters."""
         s = self.engine.stats()
         s["scheduler"] = {
             **self.counters,

@@ -25,14 +25,16 @@ Covers, per the PR 8 acceptance list:
 * the dispatcher's phase spans, over a sharded engine — exclusive,
   one ``bid`` per bucket, covering the dispatcher thread; every
   blocking device→host read is a ``sync`` child of a phase (or of
-  ``admit``), exactly eight per masked bucket, counted by site;
+  ``admit``), exactly five per masked bucket, counted by site;
 * the span API — parent, thread, inherited ``bid``, the profiler
   annotation it enters — and the wall-clock Chrome export.
 """
 import json
 import logging
+import os
 import subprocess
 import sys
+import textwrap
 import urllib.request
 from concurrent.futures import Future
 from pathlib import Path
@@ -73,8 +75,7 @@ def _fresh_obs():
     obs.reset()
 
 
-@pytest.fixture(scope="module")
-def vit_engine_factory():
+def _vit_engine_factory():
     vc = ViTConfig(name="vt-obs", img_res=32, patch=8, n_layers=3,
                    d_model=32, n_heads=2, d_ff=64, n_classes=10,
                    exit_layers=(0, 1))
@@ -89,6 +90,11 @@ def vit_engine_factory():
             dart=DartParams(tau=jnp.full((2,), 0.2), coef=jnp.ones(2),
                             beta_diff=0.3), **kw)
     return make
+
+
+@pytest.fixture(scope="module")
+def vit_engine_factory():
+    return _vit_engine_factory()
 
 
 @pytest.fixture(scope="module")
@@ -265,8 +271,8 @@ def test_spans_reconcile_with_engine_telemetry(vit_engine_factory,
 # ---------------------------------------------------------------------------
 DISPATCHER = "AsyncDartServer"          # the dispatcher thread's name
 #: the masked path's blocking reads per bucket: five outputs in
-#: ``fetch``, three latency-ring reads in ``fold``
-SYNCS_PER_BUCKET = {("fetch", "output"): 5, ("fold", "latency_ring"): 3}
+#: ``fetch`` (the latency window lives on the host: ``fold`` reads none)
+SYNCS_PER_BUCKET = {("fetch", "output"): 5}
 
 
 @pytest.fixture(scope="module")
@@ -357,8 +363,111 @@ def test_masked_path_syncs_per_bucket(served_traced):
     fam = served_traced["fams"]["dart_device_syncs_total"]
     by_site = {lab["site"]: v for _, lab, v in fam["samples"]}
     assert by_site == {"output": 5.0 * len(bids),
-                       "latency_ring": 3.0 * len(bids),
                        "admit_alpha": float(served_traced["n_req"])}
+
+
+def test_launched_buckets_counted_by_behind(served_traced):
+    """Every ``gather`` carries ``behind`` (in-flight buckets not yet
+    computed when the next one launches), and
+    ``dart_buckets_launched_total{behind}`` counts each bucket once."""
+    spans = served_traced["spans"]
+    n_buckets = len([s for s in spans if s["name"] == "bucket"])
+    gathers = [s for s in spans if s["name"] == "gather"]
+    assert len(gathers) == n_buckets
+    for s in gathers:
+        assert isinstance(s["behind"], int) and 0 <= s["behind"] <= 2, s
+    fam = served_traced["fams"]["dart_buckets_launched_total"]
+    by_behind = {lab["behind"]: v for _, lab, v in fam["samples"]}
+    assert sum(by_behind.values()) == n_buckets
+    for b, v in by_behind.items():
+        assert v == sum(s["behind"] == int(b) for s in gathers)
+
+
+# ---------------------------------------------------------------------------
+# the request window lives on the host
+# ---------------------------------------------------------------------------
+def _check_request_window(images) -> None:
+    """Serve a traced stream (half the requests past a zero deadline)
+    through a sharded engine over every device: ``fold`` makes no
+    blocking read, and ``stats()["requests"]`` is exactly what the
+    futures report."""
+    obs.reset()
+    obs.configure(enabled=True)
+    eng = _vit_engine_factory()(mesh=make_serving_mesh())
+    assert eng.n_replicas == jax.device_count()
+    srv = AsyncDartServer(eng, SchedulerConfig(max_batch=8, flush_ms=1.0))
+    futs = [srv.submit(images[i:i + 4],
+                       deadline_ms=0.0 if i % 8 else 10_000)
+            for i in range(0, len(images), 4)]
+    outs = [f.result(timeout=120) for f in futs]
+    srv.close()
+    spans = obs.get_tracer().spans()
+    assert any(s["name"] == "fold" for s in spans)
+    assert not [s for s in spans
+                if s["name"] == "sync" and s["parent"] == "fold"]
+    req = srv.stats()["requests"]
+    lats = np.asarray([o["latency_ms"] for o in outs], np.float32)
+    assert req["requests"] == len(outs)
+    n_miss = sum(o["deadline_missed"] for o in outs)
+    assert req["deadline_miss"] == n_miss >= len(outs) // 2
+    got = req["latency_ms"]
+    np.testing.assert_array_equal(
+        [got["p50"], got["p95"], got["p99"]],
+        np.percentile(lats, [50.0, 95.0, 99.0]).astype(np.float64))
+    obs.reset()
+
+
+WINDOW_SCRIPT = textwrap.dedent("""
+    import sys
+    sys.path[:0] = [%r, %r]
+    import numpy as np
+    from repro.data.datasets import make_batch
+    import test_obs
+    x, _ = make_batch(test_obs.DATA, range(64), split="eval")
+    test_obs._check_request_window(np.asarray(x))
+    print("WINDOW_OK")
+""" % (str(ROOT / "src"), str(ROOT / "tests")))
+
+
+@pytest.mark.parametrize("n_devices", [1, 8])
+def test_fold_reads_nothing_and_window_matches_futures(n_devices,
+                                                       eval_images):
+    if n_devices == 1:
+        _check_request_window(eval_images)
+        return
+    env = {**os.environ, "XLA_FLAGS":
+           f"--xla_force_host_platform_device_count={n_devices}"}
+    r = subprocess.run([sys.executable, "-c", WINDOW_SCRIPT], env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "WINDOW_OK" in r.stdout
+
+
+def test_completion_reads_no_leaf_of_the_engine_state(vit_engine_factory,
+                                                      eval_images,
+                                                      monkeypatch):
+    """Completing a bucket hands ``to_host`` that bucket's outputs and
+    never a leaf of the engine's current state — the state is the
+    output of the newest step, so reading it would wait for that step
+    and drain the device."""
+    eng = vit_engine_factory(mesh=make_serving_mesh())
+    seen = []
+
+    def recording(value, site):
+        leaves = jax.tree.leaves(eng.state)
+        seen.append((site, any(value is leaf for leaf in leaves)))
+        return np.asarray(value)
+
+    original = obs.to_host
+    for name, mod in list(sys.modules.items()):
+        if name.startswith("repro.") \
+                and getattr(mod, "to_host", None) is original:
+            monkeypatch.setattr(mod, "to_host", recording)
+    outs, st, _ = _serve_stream(eng, eval_images)
+    assert st["requests"]["requests"] == len(outs)
+    sites = [site for site, _ in seen]
+    assert sites.count("output") >= 5
+    assert not [site for site, is_leaf in seen if is_leaf], seen
 
 
 def test_request_spans_carry_their_bucket(served_traced):

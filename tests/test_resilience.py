@@ -471,6 +471,36 @@ def test_snapshot_roundtrip_restores_learned_priors(tmp_path):
     pool2.close()
 
 
+def test_snapshot_roundtrip_keeps_request_window(tmp_path):
+    """``snapshot`` writes the primary engine's request window into the
+    checkpoint; ``restore_snapshot`` into fresh engines gives the same
+    ``stats()["requests"]``."""
+    e0, e1, _ = _pool_engines()
+    pool = EnginePool({"e0": e0, "e1": e1}, _rcfg(), heartbeat=False)
+    srv = PooledDartServer(pool, SchedulerConfig(edges=(), max_batch=4),
+                           start=False)
+    futs = [srv.submit(_images(13, 2), deadline_ms=d)
+            for d in (0.0, 1e4, 0.0, 1e4)]
+    _drive(srv, futs)
+    [f.result(timeout=5) for f in futs]
+    before = srv.stats()["requests"]
+    assert before["deadline_miss"] >= 2
+    snap = str(tmp_path / "snap")
+    srv.snapshot(snap, step=3)
+    srv.close()
+    pool.close()
+
+    pool2 = EnginePool({"e0": _mk_engine(), "e1": _mk_engine()}, _rcfg(),
+                       heartbeat=False)
+    srv2 = PooledDartServer(pool2, SchedulerConfig(edges=(), max_batch=4),
+                            start=False)
+    assert "requests" not in srv2.stats()
+    assert srv2.restore_snapshot(snap) == 3
+    assert srv2.stats()["requests"] == before
+    srv2.close()
+    pool2.close()
+
+
 def test_pooled_lm_session_survives_engine_death():
     if "lm" not in _CACHE:
         _CACHE["lm"] = unzip(lm_init(jax.random.key(0), LMCFG))[0]

@@ -265,21 +265,53 @@ def test_latency_percentiles_match_recomputed_reference(vit_engine_factory,
 
 
 def test_latency_ring_buffer_wraps(vit_engine_factory):
-    """EngineState latency fold: ring overwrite keeps the LAST w records
-    and the lifetime counters keep counting."""
+    """Host request window: ring overwrite keeps the LAST w records and
+    the lifetime counters keep counting."""
     eng = vit_engine_factory()
-    state = ST.EngineState.create(3, eng.acfg, lat_window=4)
+    win, _ = ST.RequestWindow.take(
+        ST.EngineState.create(3, eng.acfg, lat_window=4))
     lats = [10.0, 20.0, 30.0, 40.0, 50.0, 60.0]
-    state = ST.record_requests(state, lats[:3], missed=[True, False, False])
-    state = ST.record_requests(state, lats[3:], missed=[False, True, False])
-    st = ST.request_stats(state)
+    win.record_requests(lats[:3], missed=[True, False, False])
+    win.record_requests(lats[3:], missed=[False, True, False])
+    st = win.stats()
     assert st["requests"] == 6 and st["deadline_miss"] == 2
     assert st["miss_rate"] == pytest.approx(2 / 6)
     window = {50.0, 60.0, 30.0, 40.0}       # last 4, ring order
-    assert set(np.asarray(state.lat_ms).tolist()) == window
+    assert set(win.lat_ms.tolist()) == window
     np.testing.assert_allclose(
         st["latency_ms"]["p95"],
         np.percentile(np.asarray(sorted(window), np.float32), 95.0))
+
+
+def test_request_window_checkpoint_roundtrip(vit_engine_factory,
+                                            eval_images, tmp_path):
+    """A served sharded engine's request window (latencies, deadline
+    misses, quote sums) survives save_state -> fresh engine ->
+    restore_state; the live state never carries it."""
+    from repro.launch.mesh import make_serving_mesh
+    eng = vit_engine_factory(mesh=make_serving_mesh())
+    clk = FakeClock()
+    srv = AsyncDartServer(eng, SchedulerConfig(max_batch=8, flush_ms=1.0),
+                          clock=clk, start=False)
+    futs = []
+    for i in range(0, 24, 2):
+        futs.append(srv.submit(eval_images[i:i + 2],
+                               deadline_ms=1.0 if i % 4 else 1e4))
+        clk.advance(0.003)
+    clk.advance(0.01)
+    srv.close()
+    assert all(f.done() for f in futs)
+    eng.record_quotes([4.0, None, 9.5], [5.0, 6.0, 7.0])
+    before = eng.stats()["requests"]
+    assert before["requests"] == 12 and before["deadline_miss"] == 6
+    assert before["quote"]["quoted"] == 2
+    assert int(eng.state.lat_count) == 0
+    eng.save_state(str(tmp_path), step=4)
+    fresh = vit_engine_factory(mesh=make_serving_mesh())
+    assert "requests" not in fresh.stats()
+    assert fresh.restore_state(str(tmp_path)) == 4
+    assert fresh.stats()["requests"] == before
+    assert int(fresh.state.lat_count) == 0
 
 
 def test_bad_request_fails_its_future_not_the_loop(vit_engine_factory,
@@ -354,6 +386,7 @@ def test_restore_pre_latency_checkpoint(vit_engine_factory, eval_images,
     assert eng2.restore_state(str(tmp_path)) == 3
     assert int(eng2.state.served) == 16     # legacy telemetry restored
     assert int(eng2.state.lat_count) == 0   # fresh latency counters
+    assert "requests" not in eng2.stats()   # and an empty window
     np.testing.assert_array_equal(np.asarray(eng2.state.exit_counts),
                                   np.asarray(eng.state.exit_counts))
 
