@@ -1,9 +1,7 @@
 PY ?= python
 export PYTHONPATH := src:$(PYTHONPATH)
 
-.PHONY: test smoke engine-test bench bench-serving bench-async bench-lm \
-    bench-cascade bench-predict bench-chaos bench-kernels bench-obs dartop \
-    perf-check docs-check deps
+.PHONY: test smoke engine-test bench docs-check deps
 
 # Tier-1 verify (ROADMAP): docs lint + the full test suite, fail-fast.
 test: docs-check
@@ -22,59 +20,6 @@ smoke:
 # Paper-protocol benchmarks (quick budget).
 bench:
 	$(PY) -m benchmarks.run
-
-# Sharded request-stream serving benchmark (8 fake CPU devices).
-bench-serving:
-	$(PY) -m benchmarks.serving_sharded
-
-# Async scheduler benchmark: open-loop Poisson load sweep vs per-request
-# eager dispatch (>= 2x sustained throughput at equal p95).
-bench-async:
-	$(PY) -m benchmarks.serving_async
-
-# Sharded bucketed LM decode session vs eager per-request decode
-# (>= 1.5x tokens/s at equal p95; JSON to artifacts/perf/).
-bench-lm:
-	$(PY) -m benchmarks.serving_lm
-
-# Difficulty-routed multi-model cascade vs biggest-member-only serving
-# (cascade sustains more samples/s at equal p95; JSON to
-# artifacts/perf/serving_cascade.json).
-bench-cascade:
-	$(PY) -m benchmarks.serving_cascade
-
-# Admission-time exit-depth prediction A/B: predictor-on vs predictor-off
-# (on beats off on sustained throughput at equal p95, DAES no worse;
-# JSON to artifacts/perf/serving_predict.json).
-bench-predict:
-	$(PY) -m benchmarks.serving_predict
-
-# Fault-tolerant serving under a kill-and-rejoin chaos schedule
-# (degraded-floor + recovery ratios and fault-plan determinism; JSON to
-# artifacts/perf/serving_chaos.json).
-bench-chaos:
-	$(PY) -m benchmarks.serving_chaos
-
-# Fused-kernel microbenchmarks vs the composed XLA reference chains
-# (dispatch backends + the >=1.3x acceptance gate; JSON to
-# artifacts/bench/).
-bench-kernels:
-	$(PY) -m benchmarks.kernels_bench
-
-# Observability overhead smoke: enabled-vs-disabled throughput ratio
-# (<=5% cost gate via perf-check) + Prometheus exposition validation
-# (JSON to artifacts/perf/obs.json, metrics to artifacts/perf/metrics.prom).
-bench-obs:
-	$(PY) -m benchmarks.serving_async --smoke
-
-# One-shot dashboard probe over the exported metrics file.
-dartop:
-	$(PY) tools/dartop.py --once --file artifacts/perf/metrics.prom
-
-# Perf regression gate: run the smoke sweep, fail on >15% regression vs
-# benchmarks/baselines/smoke.json.
-perf-check:
-	$(PY) -m benchmarks.perf_iterate --check
 
 # Lint docs/ + README: compile python snippets, validate intra-repo links.
 docs-check:

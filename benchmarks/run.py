@@ -1,6 +1,6 @@
-"""Benchmark entrypoint: one benchmark per paper table/figure + the
-roofline reader.  Prints CSV blocks per benchmark and writes JSON
-artifacts under artifacts/bench/.
+"""Benchmark entrypoint: one benchmark per paper table/figure (Table I,
+Table II, Fig. 2) plus the §III.B overhead measurement.  Prints CSV
+blocks per benchmark and writes JSON artifacts under artifacts/bench/.
 
 Budget: REPRO_BENCH_BUDGET = quick (default) | std | full.
 """
@@ -13,17 +13,14 @@ import time
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
-                    help="comma list: table1,table2,fig2,overhead,"
-                         "kernels,roofline")
+                    help="comma list: table1,table2,fig2,overhead")
     args = ap.parse_args()
     wanted = set(args.only.split(",")) if args.only else None
 
-    from benchmarks import table1, table2, fig2, overhead, kernels_bench, \
-        roofline
+    from benchmarks import table1, table2, fig2, overhead
 
-    benches = [("overhead", overhead.main), ("kernels", kernels_bench.main),
-               ("table1", table1.main), ("table2", table2.main),
-               ("fig2", fig2.main), ("roofline", roofline.main)]
+    benches = [("overhead", overhead.main), ("table1", table1.main),
+               ("table2", table2.main), ("fig2", fig2.main)]
     t_all = time.time()
     for name, fn in benches:
         if wanted and name not in wanted:

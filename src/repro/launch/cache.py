@@ -1,8 +1,9 @@
 """JAX persistent compilation cache for the repo's entry points.
 
 ``enable_compile_cache()`` is called once at the start of each entry
-point (``chip_smoke.py``, the benchmarks, the examples); importing a
-library module never turns the cache on.
+point (``chip_smoke.py``, ``bench/``, the paper-protocol scripts in
+``benchmarks/``, the examples); importing a library module never turns
+the cache on.
 
 * ``JAX_COMPILATION_CACHE_DIR`` set: JAX itself reads it, so the cache
   lives there and nothing else is configured here.

@@ -3,7 +3,7 @@
 Every builder returns a ``StepBundle``: the step function, abstract
 example inputs (ShapeDtypeStructs — *no allocation*), and input shardings
 resolved from the logical-axis rules.  ``launch.dryrun`` lowers and
-compiles these; ``benchmarks.roofline`` reads their cost analyses.
+compiles these and records their cost analyses.
 
 The steps are the *real* production steps (optimizer update included for
 training; DART routing included for serving) — not stripped-down facsimiles.

@@ -4,8 +4,8 @@ Chaos testing only earns its keep when a failure found once can be
 found again: every fault here is driven by a seeded, serializable
 :class:`FaultPlan` replayed through named CUT POINTS on the serving hot
 path, and the injector records an injection TRACE so two runs of the
-same plan over the same call sequence can be diffed for identity (the
-CI determinism check in ``benchmarks/serving_chaos.py``).
+same plan over the same call sequence can be diffed for identity
+(``tests/test_resilience.py`` replays a plan twice and checks it).
 
 Cut points (where :meth:`FaultInjector.fire` is called from):
 
@@ -154,9 +154,10 @@ class FaultInjector:
     for the caller to apply (only the caller knows the output shape).
 
     ``trace`` is the replay record: one dict per injection, in firing
-    order — ``benchmarks/serving_chaos.py`` replays a plan twice over a
-    scripted call sequence and asserts trace identity.  Each fault in
-    the plan fires at most once.
+    order — ``tests/test_resilience.py::
+    test_same_plan_replayed_twice_yields_identical_traces`` replays a
+    plan twice over a scripted call sequence and asserts trace identity.
+    Each fault in the plan fires at most once.
     """
 
     def __init__(self, plan: FaultPlan | None = None, *,

@@ -15,13 +15,13 @@ http_port=...)``) and renders, per refresh:
   recompile and xla-fallback counters.
 
 Usage:
-    python tools/dartop.py --file artifacts/perf/metrics.prom
+    python tools/dartop.py --file metrics.prom
     python tools/dartop.py --url http://127.0.0.1:9099/metrics
-    python tools/dartop.py --once --json --file metrics.prom   # CI probe
+    python tools/dartop.py --once --json --file metrics.prom
 
 ``--once`` renders a single frame and exits (non-zero if the source is
 missing or unparseable); ``--json`` emits the parsed summary instead of
-the ANSI view, for scripts and the CI smoke job.
+the ANSI view, for scripts.
 """
 from __future__ import annotations
 
