@@ -175,6 +175,10 @@ class CascadeAsyncServer(AsyncDartServer):
     def _make_planner(self, cfg):
         return CascadePlanner(self.engine, edges=cfg.edges)
 
+    def _mixable_lanes(self, key) -> list:
+        """None: a lane names the member its bucket runs on."""
+        return []
+
     # -- dispatch -------------------------------------------------------
     def _infer_batch(self, reqs: list, x, alpha) -> dict:
         member = reqs[0].lane[0]

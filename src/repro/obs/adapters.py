@@ -73,15 +73,22 @@ def record_admit(sched, req, action: str, span) -> None:
 
 
 def record_bucket(sched, reqs: list, reason: str, now: float,
-                  bid: int) -> None:
+                  bid: int, rows: dict) -> None:
     """One flushed bucket: its id, which lane, which requests and how
-    many samples, and WHY it flushed (deadline pressure / size / hold /
-    forced)."""
+    many samples, WHY it flushed (deadline pressure / size / hold /
+    forced), and how ``RequestQueue.take`` came by its samples
+    (``rows``: ``fifo`` / ``skip_ahead`` / ``other_lane``)."""
     OBS.tracer.record("bucket", ts=now, lane=reqs[0].lane, bid=bid,
                       rids=[r.rid for r in reqs], n_requests=len(reqs),
-                      n_samples=sum(r.n for r in reqs), reason=reason)
+                      n_samples=sum(r.n for r in reqs), reason=reason,
+                      **rows)
     OBS.registry.counter("dart_flushes_total", "bucket flushes by reason",
                          ("reason",)).inc(1, reason=reason)
+    by_order = OBS.registry.counter(
+        "dart_bucket_rows_total",
+        "bucket samples by how the flush took them", ("order",))
+    for order, n in rows.items():
+        by_order.inc(n, order=order)
 
 
 def record_assembly(path: str, behind: int) -> None:

@@ -27,6 +27,10 @@ Flush policy (size-or-deadline):
   them until deadline pressure (or a full bucket) maximizes
   consolidation at exactly the loads where it pays.
 
+Packing: the flushed lane's head goes first, then its other requests
+first-fit in order (one that does not fit is skipped, not a stop), then
+first-fit from the lanes ``_mixable_lanes`` names (``RequestQueue.take``).
+
 Pipelining: with a sharded engine, dispatched outputs stay ON DEVICE
 (PR 2 left them lazy precisely for this) — the loop keeps up to
 ``pipeline_depth`` buckets in flight and only materializes (resolving
@@ -202,6 +206,13 @@ class _BucketScheduler:
         """Largest sample count one dispatch can serve as one shape."""
         return self.cfg.max_batch
 
+    def _mixable_lanes(self, key) -> list:
+        """Lanes whose requests may top up a bucket flushed from lane
+        ``key`` (``RequestQueue.take``'s ``others``).  Default: none, so
+        buckets stay lane-pure — right wherever a bucket's cost depends
+        on which lane its rows came from."""
+        return []
+
     # -- lifecycle ------------------------------------------------------
     def start(self) -> None:
         if self._thread is not None:
@@ -307,27 +318,29 @@ class _BucketScheduler:
             reqs = None
             if sel is not None:
                 key, reason, force = sel
-                reqs = self.queue.take(key, self.max_batch,
-                                       self._bucket_key,
-                                       min_fill=self.cfg.min_fill,
-                                       force=force)
+                reqs, rows = self.queue.take(
+                    key, self.max_batch, self._bucket_key,
+                    min_fill=self.cfg.min_fill, force=force,
+                    others=self._mixable_lanes(key))
             if OBS.enabled:
                 sp.set(reason=reason if reqs else None)
         if reqs:
             self.counters[f"flush_{reason}"] += 1
-            self._dispatch_safe(reqs, reason)
+            self._dispatch_safe(reqs, reason, rows)
             return True
         return self._drain_one()
 
-    def _dispatch_safe(self, reqs: list, reason: str) -> None:
+    def _dispatch_safe(self, reqs: list, reason: str, rows: dict) -> None:
         """A bad bucket must not kill the dispatcher: an exception from
         the engine fails THIS bucket's futures and the loop lives on
         (a shape-mismatched input would otherwise strand every pending
-        future behind a dead daemon thread)."""
+        future behind a dead daemon thread).  ``rows``: the samples
+        ``take`` came by each way."""
         bucket = NULL_SPAN
         if OBS.enabled:
             bid, self._bid = self._bid, self._bid + 1
-            OBS_A.record_bucket(self, reqs, reason, self._clock(), bid)
+            OBS_A.record_bucket(self, reqs, reason, self._clock(), bid,
+                                rows)
             bucket = OBS.tracer.bucket(bid)
         try:
             with bucket:
@@ -356,12 +369,13 @@ class _BucketScheduler:
                 break
             for key in keys:
                 while True:
-                    reqs = self.queue.take(key, self.max_batch,
-                                           self._bucket_key, force=True)
+                    reqs, rows = self.queue.take(
+                        key, self.max_batch, self._bucket_key, force=True,
+                        others=self._mixable_lanes(key))
                     if not reqs:
                         break
                     self.counters["flush_forced"] += 1
-                    self._dispatch_safe(reqs, "forced")
+                    self._dispatch_safe(reqs, "forced", rows)
         while self._drain_one():
             pass
 
@@ -472,6 +486,18 @@ class AsyncDartServer(_BucketScheduler):
 
     def _max_batch_cap(self) -> int:
         return self.engine.compactor.max_bucket
+
+    def _mixable_lanes(self, key) -> list:
+        """Every other lane where a bucket's cost does not depend on its
+        rows' difficulty: masked mode without a predictor runs every row
+        through every stage, so a lane-pure bucket costs what a mixed
+        one does.  Compacted mode (stages shrink as rows exit) and
+        ``predict`` (head-skip from the bucket's smallest α) keep lanes
+        pure.  A masked step that skipped exited rows would end the
+        exemption here."""
+        if self.cfg.mode != "masked" or self.predictor is not None:
+            return []
+        return [k for k in self.queue.keys() if k != key]
 
     def _admit(self, x, deadline_ms, priority, *, now, **kw) -> Request:
         x = np.asarray(x)

@@ -4,9 +4,13 @@ backpressure.
 Lanes are FIFO deques keyed by whatever the scheduler packs together
 (difficulty class for classifier serving, ``(seq_len, n_new)`` for LM
 decode).  Keeping lanes cost-homogeneous is the difficulty-aware part
-of the design: a bucket flushed from one lane contains requests with
-similar predicted exit depth, so one hard straggler never drags a
-bucket of easy requests through every stage.
+of the design wherever a bucket's cost depends on its rows (compacted
+stages, predicted head-skip, cascade members, LM shapes): a bucket
+flushed from one lane contains requests with similar predicted exit
+depth, so one hard straggler never drags a bucket of easy requests
+through every stage.  Where it does not (the masked step runs every
+row through every stage), the scheduler lets ``take`` top a bucket up
+from the other lanes.
 
 Backpressure triggers when a lane holds ``max_queue`` requests:
 
@@ -29,6 +33,8 @@ from collections import deque
 from repro.serving.request import Request, RequestRejected, RequestShed
 
 POLICIES = ("shed", "reject", "degrade-alpha")
+#: how ``take`` came by a bucket's samples (see its docstring)
+ORDERS = ("fifo", "skip_ahead", "other_lane")
 
 
 class RequestQueue:
@@ -180,34 +186,66 @@ class RequestQueue:
     # flush
     # ------------------------------------------------------------------
     def take(self, key, max_samples: int, bucket_key, *,
-             min_fill: float = 0.5, force: bool = False) -> list[Request]:
-        """Pop a FIFO run of whole requests totalling ≤ ``max_samples``.
+             min_fill: float = 0.5, force: bool = False,
+             others=()) -> tuple[list[Request], dict]:
+        """Pop whole requests totalling ≤ ``max_samples``, first-fit.
 
-        ``bucket_key(n)`` maps a sample count to its padded compiled
-        shape.  Unless ``force`` (deadline pressure), the run stops
-        before a request that would grow the padded shape into the next
-        bucket while filling it below ``min_fill`` — flushing now at
-        the smaller bucket beats padding waste at the larger one."""
+        The lane's head is always taken (alone when it exceeds
+        ``max_samples``: the engine chunk-splits it).  The walk then
+        goes on through the lane in order, SKIPPING a request that does
+        not fit instead of stopping at it, and then through the lanes
+        ``others`` in admission order (``rid``).  A request does not
+        fit when it would overflow ``max_samples`` or, unless ``force``
+        (deadline pressure), grow the padded shape ``bucket_key(n)``
+        into the next bucket while filling it below ``min_fill`` —
+        flushing now at the smaller bucket beats padding waste at the
+        larger one.
+
+        So the FIFO run that stopping at the first misfit would take is
+        a prefix of the result, and a skipped request is overtaken only
+        by requests that use room it could not use: with ``p`` requests
+        ahead of it in its lane, it is taken within ``p + 1`` takes of
+        that lane.
+
+        Returns the requests and the samples taken each way (``ORDERS``):
+        ``fifo`` (the FIFO run), ``skip_ahead`` (later in the lane, past
+        a skipped request) and ``other_lane`` (from ``others``)."""
         with self._lock:
             lane = self._lanes.get(key)
-            out: list[Request] = []
-            total = 0
-            while lane:
-                nxt = lane[0]
-                new_total = total + nxt.n
-                if new_total > max_samples:
-                    if not out:
-                        # oversized single request: dispatch it alone
-                        # (the engine chunk-splits internally)
-                        out.append(lane.popleft())
+            rows = dict.fromkeys(ORDERS, 0)
+            if not lane:
+                return [], rows
+
+            def fits(total: int, n: int) -> bool:
+                new = total + n
+                if new > max_samples:
+                    return False
+                if force:
+                    return True
+                b_new = bucket_key(new)
+                return b_new <= bucket_key(total) or new / b_new >= min_fill
+
+            head = lane.popleft()
+            out, total = [head], head.n
+            rows["fifo"] = head.n
+            order = "fifo"
+            mixed = sorted((r for k in others if k != key
+                            for r in self._lanes.get(k, ())),
+                           key=lambda r: r.rid)
+            for r in [*lane, *mixed]:
+                if total >= max_samples:
                     break
-                if out and not force:
-                    b_old, b_new = bucket_key(total), bucket_key(new_total)
-                    if b_new > b_old and new_total / b_new < min_fill:
-                        break
-                out.append(lane.popleft())
-                total = new_total
-            return out
+                if r.lane != key:
+                    order = "other_lane"
+                if not fits(total, r.n):
+                    if order == "fifo":
+                        order = "skip_ahead"
+                    continue
+                self._lanes[r.lane].remove(r)
+                out.append(r)
+                total += r.n
+                rows[order] += r.n
+            return out, rows
 
     def drain(self) -> list[Request]:
         """Pop everything (close/shutdown path), FIFO by admission id."""

@@ -26,6 +26,14 @@ from repro.parallel.sharding import unzip
 from repro.serving import (AsyncDartServer, RequestShed, RequestRejected,
                            SchedulerConfig)
 
+try:
+    from hypothesis import given, settings, strategies as st
+except ImportError:
+    # deterministic fallback (raises under REPRO_REQUIRE_HYPOTHESIS=1)
+    from _hypothesis_compat import given, settings, strategies as st
+
+from _prop import examples
+
 DATA = DatasetConfig(name="synth-cifar", n_train=128, n_eval=128)
 COSTS = [0.4, 0.7, 1.0]
 
@@ -80,10 +88,11 @@ def test_deadline_flush_preempts_size_flush(vit_engine_factory, eval_images):
         eng, SchedulerConfig(max_batch=8, flush_ms=50.0, margin_ms=5.0,
                              pipeline_depth=0, edges=(med,)),
         clock=clock, start=False)
-    # lane 0 (easy): size-ready; lane 1 (hard): one small request whose
-    # deadline falls inside the scheduling slack
+    # lane 0 (easy): size-ready; lane 1 (hard): one request whose
+    # deadline falls inside the scheduling slack, filling its bucket so
+    # no request of lane 0 tops it up
     size_futs = [srv.submit(easy[i:i + 3]) for i in range(0, 9, 3)]
-    ddl_fut = srv.submit(hard[:2], deadline_ms=4.0)
+    ddl_fut = srv.submit(hard[:8], deadline_ms=4.0)
     assert srv.pump()                       # 1st decision: deadline lane
     assert ddl_fut.done() and not any(f.done() for f in size_futs)
     assert srv.counters["flush_deadline"] == 1
@@ -494,3 +503,294 @@ def test_lm_session_matches_direct_generate():
     # all four callers shared one bucketed decode loop
     assert sess.counters["flush_forced"] == 1
     assert sess.stats()["requests"]["requests"] == 4
+
+
+# ---------------------------------------------------------------------------
+# bucket packing: first-fit within the lane, top-up across lanes
+# ---------------------------------------------------------------------------
+def _req(rid, n, lane=0):
+    from concurrent.futures import Future
+
+    from repro.serving.request import Request
+    return Request(rid=rid, x=np.zeros((n, 1), np.float32), n=n,
+                   alpha=np.zeros(n, np.float32), lane=lane,
+                   predicted_cost=1.0, priority=0, t_submit=float(rid),
+                   deadline_s=None, future=Future())
+
+
+def _pow2_key(cap):
+    """Padded shape of n samples: the next power of two, up to ``cap``
+    (n itself beyond it)."""
+    return lambda n: n if n > cap else 1 << max(n - 1, 0).bit_length()
+
+
+def _queue(sizes, lanes=None):
+    from repro.serving.queue import RequestQueue
+    q = RequestQueue()
+    for rid, n in enumerate(sizes):
+        q.push(_req(rid, n, 0 if lanes is None else lanes[rid]))
+    return q
+
+
+def _fifo_run(lane, max_samples, bucket_key, min_fill, force):
+    """The run that stopping at the first request that does not fit
+    would take (the rule before first-fit): the reference prefix."""
+    out, total = [], 0
+    for r in lane:
+        new_total = total + r.n
+        if new_total > max_samples:
+            if not out:
+                out.append(r)
+            break
+        if out and not force:
+            b_old, b_new = bucket_key(total), bucket_key(new_total)
+            if b_new > b_old and new_total / b_new < min_fill:
+                break
+        out.append(r)
+        total = new_total
+    return out
+
+
+def test_take_fills_past_a_request_that_does_not_fit():
+    """A 21 then a 13 no longer flushes a bucket of 21: the 13 is
+    skipped and the 8 and 2 behind it fill the bucket to 31."""
+    key = lambda n: 8 if n <= 8 else 32               # noqa: E731
+    q = _queue([21, 13, 8, 2])
+    reqs, rows = q.take(0, 32, key)
+    assert [r.rid for r in reqs] == [0, 2, 3]         # head first
+    assert rows == {"fifo": 21, "skip_ahead": 10, "other_lane": 0}
+    assert [r.rid for r in q.take(0, 32, key)[0]] == [1]
+    assert q.take(0, 32, key) == ([], {"fifo": 0, "skip_ahead": 0,
+                                       "other_lane": 0})
+
+
+def test_take_keeps_the_fifo_run_as_prefix():
+    """Per take, the FIFO run is a prefix of the first-fit run."""
+    key = _pow2_key(32)
+    sizes = [5, 13, 21, 1, 8, 2, 32, 5, 1, 13, 2]
+    q, ref = _queue(sizes), [_req(i, n) for i, n in enumerate(sizes)]
+    while ref:
+        fifo = [r.rid for r in _fifo_run(ref, 32, key, 0.5, False)]
+        got = [r.rid for r in q.take(0, 32, key)[0]]
+        assert got[:len(fifo)] == fifo and len(got) >= len(fifo)
+        ref = [r for r in ref if r.rid not in got]
+
+
+def test_take_honours_min_fill_without_force():
+    """min_fill still refuses a request that would grow the bucket
+    mostly empty, and the walk goes on past it; force drops min_fill
+    but keeps the walk."""
+    key = _pow2_key(16)
+    # 8+3 = 11/16 < 0.75: skipped; 8+5 = 13/16: taken; 13+2 stays in
+    # the 16-bucket: taken
+    reqs, rows = _queue([8, 3, 5, 2]).take(0, 16, key, min_fill=0.75)
+    assert [r.rid for r in reqs] == [0, 2, 3]
+    assert rows == {"fifo": 8, "skip_ahead": 7, "other_lane": 0}
+    reqs, _ = _queue([8, 3]).take(0, 16, key, min_fill=0.75)
+    assert [r.rid for r in reqs] == [0]
+    reqs, rows = _queue([8, 3, 5, 2]).take(0, 16, key, min_fill=0.75,
+                                           force=True)
+    assert [r.rid for r in reqs] == [0, 1, 2]       # 16: full
+    assert rows["fifo"] == 16
+
+
+def test_skipped_request_is_served_within_bounded_flushes():
+    """A skipped request is overtaken only by requests using room it
+    could not use; with p requests ahead of it in its lane it leaves
+    within p + 1 takes of that lane, however many small requests keep
+    arriving behind it."""
+    key = lambda n: 8 if n <= 8 else 32               # noqa: E731
+    q = _queue([21, 30, 13])                          # the 13: p = 2
+    rid, served_at = 3, None
+    for take in range(1, 4):
+        for _ in range(4):                            # small arrivals
+            q.push(_req(rid, 2))
+            rid += 1
+        if 2 in [r.rid for r in q.take(0, 32, key)[0]]:
+            served_at = take
+            break
+    assert served_at == 3                             # the bound, reached
+
+
+def _serve(srv, reqs):
+    """Submit ``reqs`` at once, expire the hold, pump once, close.  Spies
+    on ``take``: returns the lanes and rows of each flushed bucket, and
+    each request's result."""
+    taken = []
+    real = srv.queue.take
+
+    def spy(*a, **kw):
+        got, rows = real(*a, **kw)
+        if got:
+            taken.append(({r.lane for r in got}, rows))
+        return got, rows
+    srv.queue.take = spy
+    futs = [srv.submit(x, **kw) for x, kw in reqs]
+    srv._clock.advance(1.0)
+    srv.pump()
+    srv.close()
+    return taken, [f.result(timeout=60) for f in futs]
+
+
+def _split_by_alpha(eng, images):
+    alpha = np.asarray(eng._alpha(jnp.asarray(images)))
+    med = float(np.median(alpha))
+    return images[alpha <= med], images[alpha > med], med
+
+
+def test_masked_server_tops_up_across_lanes(vit_engine_factory,
+                                            eval_images):
+    """Masked without a predictor, a bucket's cost does not depend on
+    its rows' difficulty: one flush serves both lanes, and every
+    request still gets the eager oracle's decisions."""
+    eng, oracle = vit_engine_factory(), vit_engine_factory()
+    easy, hard, med = _split_by_alpha(eng, eval_images)
+    srv = AsyncDartServer(
+        eng, SchedulerConfig(max_batch=16, pipeline_depth=0,
+                             edges=(med,)), clock=FakeClock(), start=False)
+    xs = [easy[:2], hard[:2], easy[2:4], hard[2:5]]
+    taken, outs = _serve(srv, [(x, {}) for x in xs])
+    # lane 0's 2+2 in the 4-bucket, topped up with lane 1's 2 and 3
+    assert taken == [({0, 1}, {"fifo": 4, "skip_ahead": 0,
+                               "other_lane": 5})]
+    assert srv.counters["flush_hold"] == 1
+    for x, out in zip(xs, outs):
+        ref = oracle.infer(x, mode="masked", record=False)
+        np.testing.assert_array_equal(out["exit_idx"],
+                                      np.asarray(ref["exit_idx"]))
+        np.testing.assert_array_equal(out["pred"], np.asarray(ref["pred"]))
+
+
+@pytest.mark.parametrize("kind", ["compacted", "predict", "cascade", "lm"])
+def test_topup_never_crosses_lanes_where_lanes_carry_cost(
+        vit_engine_factory, eval_images, kind):
+    """Compacted stages, predicted head-skip, cascade members and LM
+    shapes make a bucket's cost depend on its lane: buckets stay
+    lane-pure, each lane's head served first."""
+    if kind == "lm":
+        from repro.engine import LMDecodeEngine
+        from repro.models.transformer_lm import LMConfig, lm_init
+        lc = LMConfig(name="lm-lanes", n_layers=2, d_model=16, n_heads=2,
+                      n_kv_heads=1, d_ff=32, vocab=16, exit_layers=(0,),
+                      max_seq=16, remat=False)
+        lm = LMDecodeEngine(lc, unzip(lm_init(jax.random.key(0), lc))[0],
+                            DartParams(tau=jnp.asarray([0.3]),
+                                       coef=jnp.ones(1), beta_diff=0.15))
+        srv = lm.session(SchedulerConfig(max_batch=8, policy="reject"),
+                         start=False, clock=FakeClock())
+        prompts = np.random.RandomState(0).randint(0, 16, (4, 4))
+        reqs = [(p, {"n_new": n}) for p, n in zip(prompts, (2, 3, 2, 3))]
+    else:
+        eng = vit_engine_factory()
+        easy, hard, med = _split_by_alpha(eng, eval_images)
+        if kind == "cascade":
+            from repro.cascade import CascadeEngine
+            eng = CascadeEngine([eng, vit_engine_factory()],
+                                member_costs=[0.5, 1.0], beta_esc=0.1)
+        srv = AsyncDartServer(eng, SchedulerConfig(
+            max_batch=16, pipeline_depth=0, edges=(med,),
+            mode="compacted" if kind == "compacted" else "masked",
+            predict="conservative" if kind == "predict" else "off"),
+            clock=FakeClock(), start=False)
+        reqs = [(x, {}) for x in (easy[:2], hard[:2], easy[2:4],
+                                  hard[2:5])]
+    assert srv._mixable_lanes(0) == []
+    taken, _ = _serve(srv, reqs)
+    assert len({lane for lanes, _ in taken for lane in lanes}) >= 2
+    for lanes, rows in taken:
+        assert len(lanes) == 1 and rows["other_lane"] == 0
+
+
+def test_bucket_rows_counter_and_span_add_up(vit_engine_factory,
+                                             eval_images):
+    """``dart_bucket_rows_total{order}`` and the ``bucket`` span's
+    ``fifo`` / ``skip_ahead`` / ``other_lane`` add up to its
+    ``n_samples``, and each order shows up on this stream."""
+    from repro import obs
+    eng = vit_engine_factory()
+    easy, hard, med = _split_by_alpha(eng, eval_images)
+    obs.reset()
+    obs.configure(enabled=True)
+    try:
+        srv = AsyncDartServer(
+            eng, SchedulerConfig(max_batch=8, pipeline_depth=0,
+                                 edges=(med,)),
+            clock=FakeClock(), start=False)
+        # lane 0: 5, 4 (skipped), 2; lane 1: 1 -> one bucket of 8
+        futs = [srv.submit(easy[:5]), srv.submit(easy[5:9]),
+                srv.submit(easy[9:11]), srv.submit(hard[:1])]
+        srv._clock.advance(1.0)
+        srv.close()
+        for f in futs:
+            f.result(timeout=60)
+        buckets = [s for s in obs.get_tracer().spans()
+                   if s["name"] == "bucket"]
+        fam = obs.get_registry().get("dart_bucket_rows_total")
+        by_order = {o: fam.value(order=o)
+                    for o in ("fifo", "skip_ahead", "other_lane")}
+    finally:
+        obs.reset()
+    assert len(buckets) == 2
+    for s in buckets:
+        assert s["fifo"] + s["skip_ahead"] + s["other_lane"] \
+            == s["n_samples"]
+    assert (buckets[0]["fifo"], buckets[0]["skip_ahead"],
+            buckets[0]["other_lane"]) == (5, 2, 1)
+    assert sum(by_order.values()) == sum(s["n_samples"] for s in buckets)
+    assert by_order == {"fifo": 9.0, "skip_ahead": 2.0, "other_lane": 1.0}
+
+
+@settings(max_examples=examples(60), deadline=None)
+@given(seed=st.integers(0, 2 ** 31 - 1), n_lanes=st.integers(1, 3),
+       mix=st.sampled_from([False, True]))
+def test_take_property_random_streams(seed, n_lanes, mix):
+    """Random size streams over random lanes, interleaved with takes of
+    random lanes: every request is taken exactly once, no bucket
+    exceeds max_batch, every take holds at least the FIFO run's samples
+    with that run as its prefix, and a request leaves within p + 1
+    takes of its lane (p: requests ahead of it at admission)."""
+    from repro.serving.queue import RequestQueue
+    rs = np.random.RandomState(seed)
+    max_batch = int(rs.choice([8, 16, 32]))
+    key = _pow2_key(max_batch)
+    min_fill = float(rs.choice([0.0, 0.5, 0.75]))
+    q = RequestQueue()
+    lanes = {k: [] for k in range(n_lanes)}      # mirror, lane order
+    budget, taken, rid = {}, [], 0
+    for _ in range(int(rs.randint(5, 40))):
+        for _ in range(int(rs.randint(0, 4))):
+            lane = int(rs.randint(n_lanes))
+            r = _req(rid, int(rs.randint(1, max_batch + 1)), lane)
+            budget[rid] = len(lanes[lane]) + 1
+            lanes[lane].append(r)
+            q.push(r)
+            rid += 1
+        live = [k for k, v in lanes.items() if v]
+        if not live:
+            continue
+        k = live[int(rs.randint(len(live)))]
+        force = bool(rs.randint(2))
+        others = [o for o in live if o != k] if mix else ()
+        fifo = _fifo_run(lanes[k], max_batch, key, min_fill, force)
+        reqs, rows = q.take(k, max_batch, key, min_fill=min_fill,
+                            force=force, others=others)
+        got = [r.rid for r in reqs]
+        assert got[:len(fifo)] == [r.rid for r in fifo]
+        n = sum(r.n for r in reqs)
+        assert n >= sum(r.n for r in fifo) and n <= max_batch
+        assert sum(rows.values()) == n
+        assert mix or rows["other_lane"] == 0
+        for r in lanes[k]:
+            budget[r.rid] -= 1
+        taken += got
+        for v in lanes.values():
+            v[:] = [r for r in v if r.rid not in got]
+        assert all(budget[r.rid] > 0 for v in lanes.values() for r in v)
+    for k in range(n_lanes):
+        while True:
+            reqs, _ = q.take(k, max_batch, key, force=True)
+            if not reqs:
+                break
+            taken += [r.rid for r in reqs]
+    assert sorted(taken) == list(range(rid))
+    assert q.empty
